@@ -112,6 +112,8 @@ def run(arch: str = "qwen2-moe-a2.7b", seq: int = 512, block: int = 128,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", default="qwen2-moe-a2.7b")
     ap.add_argument("--seq", type=int, default=512)

@@ -137,6 +137,8 @@ def run(csv=True, requests=REQUESTS):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import argparse
     p = argparse.ArgumentParser()
     p.add_argument("--requests", type=int, default=REQUESTS,

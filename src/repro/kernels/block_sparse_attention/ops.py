@@ -26,12 +26,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import on_tpu
 from repro.kernels.block_sparse_attention import kernel as K
 from repro.kernels.block_sparse_attention import ref as R
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # ------------------------------------------------------- pooled K means

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -78,6 +77,83 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
+def gqa_slab_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def gqa_slab_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, kv_heads,
+                  scale, qpos0, kpos0, klen, window, ks_ref=None,
+                  vs_ref=None):
+    """One online-softmax step of every query head over one K/V slab.
+
+    The layout is the one Mosaic lowers: q_ref is a head-major
+    (1, H, N, dh) block, so the rep query heads of kv head g collapse
+    into one [rep*N, dh] operand (row r is query token r % N); the slab
+    refs are (1, blk, Kv, dh) and head g's [blk, dh] keys are sliced
+    from the loaded slab. Every contraction is a plain 2-D dot. Scratch:
+    m/l (Kv, rep*N, 1) and acc (Kv, rep*N, dh), f32.
+
+    qpos0/kpos0: absolute positions of query row 0 and slab key 0;
+    klen: valid key count. ks_ref/vs_ref: optional (1, Kv, 1) f32
+    per-kv-head scales of an int8 slab (kernels/kv_quant scheme),
+    applied in VMEM before the dots."""
+    _, H, N, dh = q_ref.shape
+    blk = k_ref.shape[1]
+    rep = H // kv_heads
+    rows = rep * N
+    qpos = qpos0 + jax.lax.broadcasted_iota(jnp.int32, (rows, blk), 0) % N
+    kpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, (rows, blk), 1)
+    mask = (kpos <= qpos) & (kpos < klen)
+    if window:
+        mask = mask & (kpos > qpos - window)
+    if ks_ref is None:
+        k_slab, v_slab = k_ref[0], v_ref[0]
+    else:
+        # dequantize the whole slab at once: a [Kv, 1] scale broadcasts
+        # over [blk, Kv, dh]; a per-head scalar scale would not lower
+        k_slab = k_ref[0].astype(jnp.float32) * ks_ref[0][None]
+        v_slab = v_ref[0].astype(jnp.float32) * vs_ref[0][None]
+    for g in range(kv_heads):
+        q = (q_ref[0, g * rep:(g + 1) * rep].astype(jnp.float32)
+             .reshape(rows, dh) * scale)
+        k = k_slab[:, g, :].astype(jnp.float32)             # [blk, dh]
+        v = v_slab[:, g, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(mask, s, NEG_INF)                      # [rows, blk]
+        m_prev = m_scr[g]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # where-guard: a row whose every key in this slab is masked while
+        # the running max is still NEG_INF would compute
+        # exp(NEG_INF - NEG_INF) == 1
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[g] = l_scr[g] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[g] = acc_scr[g] * corr + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_scr[g] = m_new
+
+
+def gqa_slab_finish(o_ref, l_scr, acc_scr, *, kv_heads):
+    """Normalize the accumulators into the head-major (1, H, N, dh)
+    output block."""
+    _, H, N, dh = o_ref.shape
+    rep = H // kv_heads
+    for g in range(kv_heads):
+        o = acc_scr[g] / jnp.maximum(l_scr[g], 1e-30)
+        o_ref[0, g * rep:(g + 1) * rep] = (
+            o.reshape(rep, N, dh).astype(o_ref.dtype))
+
+
+def gqa_slab_scratch(H, N, dh, kv_heads):
+    rows = (H // kv_heads) * N
+    return [pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, dh), jnp.float32)]
+
+
 def _flash_rows_kernel(p0_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                        m_scr, l_scr, acc_scr, *, block_k, kv_heads,
                        scale, window):
@@ -86,43 +162,15 @@ def _flash_rows_kernel(p0_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        gqa_slab_init(m_scr, l_scr, acc_scr)
 
     pos0 = p0_ref[b]
-    N = q_ref.shape[1]
+    N = q_ref.shape[2]
 
     def compute():
-        q = q_ref[0].astype(jnp.float32) * scale          # [N, H, dh]
-        H, dh = q.shape[1], q.shape[2]
-        rep = H // kv_heads
-        qg = q.reshape(N, kv_heads, rep, dh)
-        k = k_ref[0].astype(jnp.float32)                  # [bk, Kv, dh]
-        s = jnp.einsum("ngrd,tgd->grnt", qg, k)
-        kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (kv_heads, rep, N, block_k), 3)
-        qpos = pos0 + jax.lax.broadcasted_iota(
-            jnp.int32, (kv_heads, rep, N, block_k), 2)
-        mask = (kpos <= qpos) & (kpos < len_ref[b])
-        if window:
-            mask = mask & (kpos > qpos - window)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_cur = jnp.max(s, axis=-1).reshape(H, N)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # where-guard: a fully-masked row in the first relevant k-block
-        # would otherwise compute exp(NEG_INF - NEG_INF) == 1
-        p = jnp.where(
-            mask, jnp.exp(s - m_new.reshape(kv_heads, rep, N)[..., None]),
-            0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1).reshape(H, N)
-        v = v_ref[0].astype(jnp.float32)
-        pv = jnp.einsum("grnt,tgd->grnd", p, v).reshape(H, N, dh)
-        acc_scr[...] = acc_scr[...] * corr[..., None] + pv
-        m_scr[...] = m_new
+        gqa_slab_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                      kv_heads=kv_heads, scale=scale, qpos0=pos0,
+                      kpos0=j * block_k, klen=len_ref[b], window=window)
 
     # skip k-blocks entirely above this ROW's causal front (per-row
     # traced pos0s — the reason the static-q_offset kernel above can't
@@ -134,8 +182,7 @@ def _flash_rows_kernel(p0_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[..., None]
-        o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
+        gqa_slab_finish(o_ref, l_scr, acc_scr, kv_heads=kv_heads)
 
 
 @functools.partial(jax.jit,
@@ -149,7 +196,9 @@ def flash_attention_rows(q, k, v, pos0s, lengths, *, block_k: int = 128,
 
     q: [B, N, H, dh] (RoPE applied); k, v: [B, S, Kv, dh]; pos0s,
     lengths: [B] int32 (scalar-prefetched — they drive the per-row
-    causal k-block skip). S % block_k == 0. Returns [B, N, H, dh] f32."""
+    causal k-block skip). S % block_k == 0. Returns [B, N, H, dh] f32.
+    The kernel runs on head-major q/o (see `gqa_slab_step`); only the
+    query block and its output are transposed, never the cache."""
     B, N, H, dh = q.shape
     S, Kv = k.shape[1], k.shape[2]
     assert S % block_k == 0 and H % Kv == 0
@@ -162,29 +211,27 @@ def flash_attention_rows(q, k, v, pos0s, lengths, *, block_k: int = 128,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, N, H, dh),
+                pl.BlockSpec((1, H, N, dh),
                              lambda b, j, p0, ln: (b, 0, 0, 0)),
                 pl.BlockSpec((1, block_k, Kv, dh),
                              lambda b, j, p0, ln: (b, j, 0, 0)),
                 pl.BlockSpec((1, block_k, Kv, dh),
                              lambda b, j, p0, ln: (b, j, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, N, H, dh),
+            out_specs=pl.BlockSpec((1, H, N, dh),
                                    lambda b, j, p0, ln: (b, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((H, N), jnp.float32),
-                pltpu.VMEM((H, N), jnp.float32),
-                pltpu.VMEM((H, N, dh), jnp.float32),
-            ],
+            scratch_shapes=gqa_slab_scratch(H, N, dh, Kv),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, N, H, dh), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((B, H, N, dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )
-    return kernel(jnp.asarray(pos0s, jnp.int32),
-                  jnp.asarray(lengths, jnp.int32), q, k, v)
+    o = kernel(jnp.asarray(pos0s, jnp.int32),
+               jnp.asarray(lengths, jnp.int32), q.transpose(0, 2, 1, 3),
+               k, v)
+    return o.transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -216,7 +263,7 @@ def flash_attention(q, k, v, *, block_q: int = 128, block_k: int = 128,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -4,12 +4,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import on_tpu
 from repro.kernels.flash_attention import kernel as K
 from repro.kernels.flash_attention import ref as R
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def mha_flash_rows(q, k, v, pos0s, lengths, *, window=None,

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 
 def _grouped_matmul_kernel(offs_ref, lhs_ref, rhs_ref, o_ref, *,
                            block_m: int):
@@ -81,7 +79,7 @@ def grouped_matmul(lhs, rhs, group_sizes, *, block_m: int = 128,
             out_specs=pl.BlockSpec((block_m, F), lambda m, e, offs: (m, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((M, F), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

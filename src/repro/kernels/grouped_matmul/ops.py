@@ -19,12 +19,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import on_tpu
 from repro.kernels.grouped_matmul import kernel as K
 from repro.kernels.grouped_matmul import ref as R
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def has_ragged_dot() -> bool:

@@ -10,6 +10,11 @@ Quantization semantics are EXACTLY ref.quantize_pages_ref /
 ref.dequantize_pages_ref (symmetric per-(page, kv-head), zero pages
 keep scale 0); tests cross-check the interpret-mode kernels against
 the oracles bit-exactly.
+
+Scales cross the kernel boundary as [P, Kv, 1] (a free reshape of the
+heap's [P, Kv]): a (1, Kv, 1) block keeps its last two dims equal to
+the array's, as the TPU tiling rule asks, and its [Kv, 1] value
+broadcasts straight against a [psz, Kv, dh] page.
 """
 from __future__ import annotations
 
@@ -20,23 +25,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.kernels.kv_quant.ref import INV_127
 
 
 def _quantize_kernel(x_ref, q_ref, s_ref):
     x = x_ref[0].astype(jnp.float32)                  # [psz, Kv, dh]
-    absmax = jnp.max(jnp.abs(x), axis=(0, 2))         # [Kv]
+    absmax = jnp.max(jnp.max(jnp.abs(x), axis=2, keepdims=True),
+                     axis=0)                          # [Kv, 1]
     s = absmax * INV_127          # reciprocal multiply, same as the ref
     safe = jnp.where(s > 0, s, 1.0)
-    q = jnp.clip(jnp.round(x / safe[None, :, None]), -127, 127)
+    q = jnp.clip(jnp.round(x / safe[None]), -127, 127)
     q_ref[0] = q.astype(jnp.int8)
     s_ref[0] = s
 
 
 def _dequantize_kernel(q_ref, s_ref, o_ref):
-    o_ref[0] = (q_ref[0].astype(jnp.float32)
-                * s_ref[0][None, :, None])
+    o_ref[0] = q_ref[0].astype(jnp.float32) * s_ref[0][None]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -50,18 +54,19 @@ def quantize_pages(x, *, interpret: bool = False):
         in_specs=[pl.BlockSpec((1, psz, Kv, dh), lambda p: (p, 0, 0, 0))],
         out_specs=(
             pl.BlockSpec((1, psz, Kv, dh), lambda p: (p, 0, 0, 0)),
-            pl.BlockSpec((1, Kv), lambda p: (p, 0)),
+            pl.BlockSpec((1, Kv, 1), lambda p: (p, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((P, psz, Kv, dh), jnp.int8),
-            jax.ShapeDtypeStruct((P, Kv), jnp.float32),
+            jax.ShapeDtypeStruct((P, Kv, 1), jnp.float32),
         ),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )
-    return kernel(x)
+    q, s = kernel(x)
+    return q, s.reshape(P, Kv)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -74,13 +79,13 @@ def dequantize_pages(q, s, *, interpret: bool = False):
         grid=(P,),
         in_specs=[
             pl.BlockSpec((1, psz, Kv, dh), lambda p: (p, 0, 0, 0)),
-            pl.BlockSpec((1, Kv), lambda p: (p, 0)),
+            pl.BlockSpec((1, Kv, 1), lambda p: (p, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, psz, Kv, dh), lambda p: (p, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((P, psz, Kv, dh), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )
-    return kernel(q, s)
+    return kernel(q, s.reshape(P, Kv, 1))

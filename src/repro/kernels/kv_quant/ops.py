@@ -12,14 +12,9 @@ serving write/gather paths rely on this):
 """
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.backend import on_tpu
 from repro.kernels.kv_quant import kernel as K
 from repro.kernels.kv_quant import ref as R
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def quantize_pages_op(x, use_kernel: bool | None = None):

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -117,7 +116,7 @@ def _paged_decode_kernel_quant(table_ref, pos_ref, q_ref, k_ref, ks_ref,
         rep = H // kv_heads
         qg = q.reshape(kv_heads, rep, dh)
         k = (k_ref[0].astype(jnp.float32)
-             * ks_ref[0][None, :, None])                  # [psz, Kv, dh]
+             * ks_ref[0][None])                           # [psz, Kv, dh]
         s = jnp.einsum("grd,tgd->grt", qg, k)             # [Kv, rep, psz]
         kpos = j * psz + jax.lax.broadcasted_iota(
             jnp.int32, (kv_heads, rep, psz), 2)
@@ -134,7 +133,7 @@ def _paged_decode_kernel_quant(table_ref, pos_ref, q_ref, k_ref, ks_ref,
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         v = (v_ref[0].astype(jnp.float32)
-             * vs_ref[0][None, :, None])                  # [psz, Kv, dh]
+             * vs_ref[0][None])                           # [psz, Kv, dh]
         pv = jnp.einsum("grt,tgd->grd",
                         p.reshape(kv_heads, rep, psz), v)
         acc_scr[...] = acc_scr[...] * corr + pv.reshape(H, dh)
@@ -206,7 +205,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, positions, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -225,7 +224,8 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
     int8 [n_pages, psz, Kv, dh] with f32 scales [n_pages, Kv]
     (kernels/kv_quant scheme). The scale slabs ride the SAME clamped
     index map as their pages, so dead pages' scale bytes are DMA-elided
-    together with their page bytes."""
+    together with their page bytes. Scales enter the kernel as
+    [n_pages, Kv, 1] (see kernels/kv_quant/kernel.py)."""
     B, H, dh = q.shape
     n_pages, psz, Kv, _ = k_pages.shape
     max_pages = page_table.shape[1]
@@ -244,7 +244,7 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
         return (tbl[b, jj], 0, 0, 0)
 
     def scale_index(b, j, tbl, pos):
-        return kv_index(b, j, tbl, pos)[:2]
+        return kv_index(b, j, tbl, pos)[:3]
 
     kernel = pl.pallas_call(
         functools.partial(_paged_decode_kernel_quant, psz=psz,
@@ -256,9 +256,9 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
             in_specs=[
                 pl.BlockSpec((1, H, dh), lambda b, j, tbl, pos: (b, 0, 0)),
                 pl.BlockSpec((1, psz, Kv, dh), kv_index),
-                pl.BlockSpec((1, Kv), scale_index),
+                pl.BlockSpec((1, Kv, 1), scale_index),
                 pl.BlockSpec((1, psz, Kv, dh), kv_index),
-                pl.BlockSpec((1, Kv), scale_index),
+                pl.BlockSpec((1, Kv, 1), scale_index),
             ],
             out_specs=pl.BlockSpec((1, H, dh),
                                    lambda b, j, tbl, pos: (b, 0, 0)),
@@ -269,11 +269,12 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )
     return kernel(jnp.asarray(page_table, jnp.int32),
                   jnp.asarray(positions, jnp.int32), q,
-                  k_pages, k_scales, v_pages, v_scales)
+                  k_pages, k_scales.reshape(n_pages, Kv, 1),
+                  v_pages, v_scales.reshape(n_pages, Kv, 1))

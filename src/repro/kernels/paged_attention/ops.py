@@ -16,14 +16,9 @@ this):
 """
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.backend import on_tpu
 from repro.kernels.paged_attention import kernel as K
 from repro.kernels.paged_attention import ref as R
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def paged_attention_op(q, k_pages, v_pages, page_table, positions, *,

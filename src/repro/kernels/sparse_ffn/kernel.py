@@ -23,15 +23,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
-
-def _live(ids, cnt, k):
+def _live(ids, cnt, k, b=None):
     """Index-map helper: clamp dead grid steps (k >= cnt) to the last
     live tile id so they re-request an already-resident slab — Pallas
     elides the repeat fetch, making dead steps DMA-free as well as
-    (via pl.when) MXU-free. ids: [K], cnt: scalar, k: grid index."""
-    return ids[jnp.minimum(k, jnp.maximum(cnt - 1, 0))]
+    (via pl.when) MXU-free. ids: [K] SMEM ref, or [B, K] with row b;
+    cnt: scalar; k: grid index. The id is read element by element:
+    Mosaic loads only scalars from SMEM, never a row."""
+    kk = jnp.minimum(k, jnp.maximum(cnt - 1, 0))
+    return ids[kk] if b is None else ids[b, kk]
 
 
 def _sparse_ffn_kernel(ids_ref, cnt_ref, x_ref, wg_ref, wu_ref, wd_ref,
@@ -97,7 +98,7 @@ def sparse_ffn(x, wg, wu, wd, tile_ids, k_valid=None, *, tile: int = 128,
                                    lambda n, k, ids, cnt: (n, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((N, D), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -176,19 +177,19 @@ def sparse_ffn_batched(x, wg, wu, wd, tile_ids, k_valid=None, *,
                 # revisited slab is already resident, no DMA issued
                 pl.BlockSpec((D, tile),
                              lambda b, n, k, ids, cnt:
-                             (0, _live(ids[b], cnt[b], k))),
+                             (0, _live(ids, cnt[b], k, b))),
                 pl.BlockSpec((D, tile),
                              lambda b, n, k, ids, cnt:
-                             (0, _live(ids[b], cnt[b], k))),
+                             (0, _live(ids, cnt[b], k, b))),
                 pl.BlockSpec((tile, D),
                              lambda b, n, k, ids, cnt:
-                             (_live(ids[b], cnt[b], k), 0)),
+                             (_live(ids, cnt[b], k, b), 0)),
             ],
             out_specs=pl.BlockSpec((1, block_n, D),
                                    lambda b, n, k, ids, cnt: (b, n, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((B, N, D), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -233,7 +234,7 @@ def dense_ffn(x, wg, wu, wd, *, tile: int = 512, block_n: int = 128,
         ],
         out_specs=pl.BlockSpec((block_n, D), lambda n, f: (n, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

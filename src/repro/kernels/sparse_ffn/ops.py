@@ -18,15 +18,11 @@ kernel on TPU without touching model code.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import on_tpu
 from repro.kernels.sparse_ffn import kernel as K
 from repro.kernels.sparse_ffn import ref as R
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _block_n_for(N: int) -> int:

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat as CP
-from repro.compat import use_mesh
 from repro.configs import ASSIGNED, ALL, get_config
 from repro.models.registry import get_model
 from repro.nn import param as PM
@@ -34,8 +32,8 @@ from repro.distributed.sharding import param_shardings
 from repro.training.optimizer import opt_state_specs
 from repro.training.train import make_loss_fn
 from repro.training.optimizer import make_optimizer
-from repro.launch.mesh import (make_production_mesh, PEAK_FLOPS_BF16,
-                               HBM_BW, ICI_BW)
+from repro.launch.mesh import (PRODUCTION_DEVICE_KIND,
+                               make_production_mesh, peaks)
 from repro.launch import shapes as SH
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
@@ -186,7 +184,7 @@ def build_lowering(cfg, shape_name: str, mesh, fused_prefill: bool = False):
         astate = {"params": aparams, "opt": aopt, "step": astep}
         out_sh = ({"params": pshard, "opt": oshard,
                    "step": None}, None)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jax.jit(step).lower(astate, abatch)
         return lowered, {"shape": shape}
 
@@ -202,7 +200,7 @@ def build_lowering(cfg, shape_name: str, mesh, fused_prefill: bool = False):
             fn = model.prefill_fused if use_fused else model.prefill
             return fn(params, cfg, batch, cache, shards=shards, **kw)
 
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jax.jit(step).lower(aparams, abatch, acache)
         return lowered, {"shape": shape}
 
@@ -227,7 +225,7 @@ def build_lowering(cfg, shape_name: str, mesh, fused_prefill: bool = False):
         return model.decode_step(params, cfg, token, cache, position,
                                  shards=shards, window=window)
 
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jax.jit(step).lower(aparams, tok["token"], acache,
                                       tok["position"])
     return lowered, {"shape": shape, "window": window}
@@ -239,7 +237,6 @@ def analyse(lowered, cfg, shape_name: str, mesh, compile_seconds=None):
     compiled = lowered.compile()
     chips = int(np.prod(list(mesh.shape.values())))
     mem = compiled.memory_analysis()
-    cost = CP.cost_analysis(compiled)
     text = compiled.as_text()
     # XLA's cost_analysis counts while bodies ONCE; analyze_hlo scales by
     # known_trip_count and derives dot flops / collective payload bytes
@@ -251,9 +248,10 @@ def analyse(lowered, cfg, shape_name: str, mesh, compile_seconds=None):
     coll_counts = hm.collective_counts
     coll_dev = hm.collective_total
 
-    compute_term = flops_dev / PEAK_FLOPS_BF16
-    memory_term = bytes_dev / HBM_BW
-    collective_term = coll_dev / ICI_BW
+    peak = peaks(PRODUCTION_DEVICE_KIND)
+    compute_term = flops_dev / peak["flops_bf16"]
+    memory_term = bytes_dev / peak["hbm_bytes_s"]
+    collective_term = coll_dev / peak["ici_bytes_s_per_link"]
     terms = {"compute": compute_term, "memory": memory_term,
              "collective": collective_term}
     bottleneck = max(terms, key=terms.get)

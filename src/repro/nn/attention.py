@@ -201,8 +201,9 @@ def attend_block_cached(params, x_block, k_cache, v_cache, pos0, *,
     positions = pos0 + jnp.arange(N)[None, :]
     theta = rope_theta if use_rope else None
     q = project_q(params, x_block, positions, theta)
+    from repro.kernels.backend import on_tpu
     from repro.kernels.flash_attention import ops as FA
-    if FA.on_tpu():
+    if on_tpu():
         # kernel-backed dense baseline (gather/mask fallback off-TPU)
         lens = (lengths if lengths is not None
                 else jnp.full((B,), S, jnp.int32))
@@ -269,15 +270,16 @@ def attend_block_rows(params, x_block, k_cache, v_cache, pos0s, *,
         o = BSA.block_sparse_prefill_op(q, k_cache, v_cache, ids, cnts,
                                         pos0s, lens, blk=N,
                                         window=window)
-        return output_proj(params, o.astype(v_cache.dtype))
+        return output_proj(params, o.astype(x_block.dtype))
+    from repro.kernels.backend import on_tpu
     from repro.kernels.flash_attention import ops as FA
-    if FA.on_tpu():
+    if on_tpu():
         # kernel-backed dense baseline (gather/mask fallback off-TPU)
         lens = (lengths if lengths is not None
                 else jnp.full((B,), S, jnp.int32))
         o = FA.mha_flash_rows(q, k_cache, v_cache, pos0s, lens,
                               window=window)
-        return output_proj(params, o.astype(v_cache.dtype))
+        return output_proj(params, o.astype(x_block.dtype))
     kj = jnp.arange(S)[None, None, :]
     valid = kj <= positions[:, :, None]                       # [B, N, S]
     if window:
@@ -286,7 +288,7 @@ def attend_block_rows(params, x_block, k_cache, v_cache, pos0s, *,
         valid = valid & (kj < lengths[:, None, None])
     mask = valid[:, None, None]                               # [B,1,1,N,S]
     o = dot_attention(q, k_cache, v_cache, mask)
-    return output_proj(params, o)
+    return output_proj(params, o.astype(x_block.dtype))
 
 
 def attend_decode(params, x_tok, k_cache, v_cache, position, *,
@@ -389,13 +391,6 @@ def kv_page_size(pages):
     [n_pages, psz, Kv, dh] pages or the int8-quantized heap
     ({"q": int8 pages, "s": f32 [n_pages, Kv]}, kernels/kv_quant)."""
     return (pages["q"] if isinstance(pages, dict) else pages).shape[1]
-
-
-def kv_dtype(pages):
-    """Dtype attention outputs cast back to: the page dtype for the raw
-    heap, the f32 compute dtype for the int8-quantized heap (int8 is a
-    storage format, never a compute dtype)."""
-    return (pages["s"] if isinstance(pages, dict) else pages).dtype
 
 
 def gather_pages(pages, page_table):
@@ -609,7 +604,7 @@ def attend_block_rows_paged(params, x_block, k_pages, v_pages, page_table,
         o = BSA.block_sparse_prefill_paged_op(
             q, k_pages, v_pages, page_table, ids, cnts, pos0s, lens,
             blk=N, window=window)
-        return output_proj(params, o.astype(kv_dtype(v_pages)))
+        return output_proj(params, o.astype(x_block.dtype))
     kc, vc = gather_kv_pages(k_pages, v_pages, page_table)
     return attend_block_rows(params, x_block, kc, vc, pos0s,
                              window=window, rope_theta=rope_theta,
@@ -630,7 +625,7 @@ def attend_decode_ragged_paged(params, x_tok, k_pages, v_pages, page_table,
     o = PA.paged_attention_op(q[:, 0], k_pages, v_pages, page_table,
                               positions, window=window,
                               use_kernel=use_kernel)
-    return output_proj(params, o[:, None].astype(kv_dtype(v_pages)))
+    return output_proj(params, o[:, None].astype(x_tok.dtype))
 
 
 def write_kv_ring(k_cache, v_cache, k_new, v_new, position, window: int):

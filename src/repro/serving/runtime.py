@@ -61,7 +61,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.compat import jit_cache_size
 from repro.core import fastforward as FF
 from repro.models.base import ModelConfig
 from repro.models.registry import get_model
@@ -566,6 +565,23 @@ class _JittedRuntime:
         return self._logits_at(self.params, hidden,
                                jnp.asarray(lengths, jnp.int32))
 
+    def _entries(self) -> dict:
+        return {
+            "prefill_block": self._prefill_block,
+            "prefill_blocks": self._prefill_blocks,
+            "decode_step": self._decode,
+            "prefill_blocks_paged": self._prefill_blocks_paged,
+            "decode_step_paged": self._decode_paged,
+            "draft_steps": self._draft,
+            "verify_chunk": self._verify,
+            "draft_steps_paged": self._draft_paged,
+            "verify_chunk_paged": self._verify_paged,
+            "copy_pages": self._copy_pages,
+            "read_pages": self._read_pages,
+            "write_pages": self._write_pages,
+            "logits_at": self._logits_at,
+        }
+
     def compile_counts(self) -> dict:
         """Distinct compilations per jitted entry point. After warmup
         (one prefill tick + one decode step) these must not grow —
@@ -573,22 +589,17 @@ class _JittedRuntime:
         `prefill_blocks` entry is covered too: its [P, N] batch width
         is static, so a churning mix of requests, offsets, and pad rows
         reuses one executable."""
-        return {
-            "prefill_block": jit_cache_size(self._prefill_block),
-            "prefill_blocks": jit_cache_size(self._prefill_blocks),
-            "decode_step": jit_cache_size(self._decode),
-            "prefill_blocks_paged": jit_cache_size(
-                self._prefill_blocks_paged),
-            "decode_step_paged": jit_cache_size(self._decode_paged),
-            "draft_steps": jit_cache_size(self._draft),
-            "verify_chunk": jit_cache_size(self._verify),
-            "draft_steps_paged": jit_cache_size(self._draft_paged),
-            "verify_chunk_paged": jit_cache_size(self._verify_paged),
-            "copy_pages": jit_cache_size(self._copy_pages),
-            "read_pages": jit_cache_size(self._read_pages),
-            "write_pages": jit_cache_size(self._write_pages),
-            "logits_at": jit_cache_size(self._logits_at),
-        }
+        return {name: int(fn._cache_size())
+                for name, fn in self._entries().items()}
+
+    def compiled_text(self, entry: str, *args, **kwargs) -> str:
+        """Optimized HLO text of `entry` (a `compile_counts` key)
+        compiled for these arguments (arrays or ShapeDtypeStructs, the
+        params excluded) — the program a call with such arguments runs,
+        e.g. to check which Pallas kernels (`tpu_custom_call`) it
+        contains."""
+        return (self._entries()[entry].lower(self.params, *args, **kwargs)
+                .compile().as_text())
 
 
 class DenseRuntime(_JittedRuntime):

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import cost_analysis
 from repro.kernels.sparse_ffn.kernel import (sparse_ffn, sparse_ffn_batched,
                                              dense_ffn)
 from repro.kernels.sparse_ffn.ref import (sparse_ffn_ref,
@@ -179,8 +178,24 @@ def test_kernel_flop_scaling():
     ids8 = jnp.arange(8, dtype=jnp.int32)
     # interpret-mode pallas doesn't expose cost; compare against the
     # analytical count through the ref path lowering instead.
-    c2 = cost_analysis(jax.jit(lambda *a: sparse_ffn_ref(*a, 128)).lower(
-        x, wg, wu, wd, ids2).compile())
-    c8 = cost_analysis(jax.jit(lambda *a: sparse_ffn_ref(*a, 128)).lower(
-        x, wg, wu, wd, ids8).compile())
+    c2 = jax.jit(lambda *a: sparse_ffn_ref(*a, 128)).lower(
+        x, wg, wu, wd, ids2).compile().cost_analysis()
+    c8 = jax.jit(lambda *a: sparse_ffn_ref(*a, 128)).lower(
+        x, wg, wu, wd, ids8).compile().cost_analysis()
     assert c8["flops"] > 3.5 * c2["flops"]
+
+
+@pytest.mark.parametrize("backend,kernel_path", [
+    ("tpu", True), ("cpu", False), ("gpu", None)])
+def test_backend_predicate_never_interprets_off_cpu(backend, kernel_path,
+                                                    monkeypatch):
+    """TPU runs the compiled kernels, CPU the XLA twins (kernels only
+    interpreted there); any other backend is refused rather than
+    quietly interpreted."""
+    from repro.kernels import backend as BK
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if kernel_path is None:
+        with pytest.raises(RuntimeError, match="no kernel path"):
+            BK.on_tpu()
+    else:
+        assert BK.on_tpu() is kernel_path

@@ -76,7 +76,6 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.distributed.sharding import param_shardings
     from repro.launch.mesh import make_host_mesh
     from repro.training.train import make_loss_fn
-    from repro import compat
 
     cfg = get_config("{arch}", reduced=True)
     mesh = make_host_mesh(2, 4)
@@ -89,11 +88,11 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
         "tokens": jax.ShapeDtypeStruct((4, 64), jnp.int32),
         "labels": jax.ShapeDtypeStruct((4, 64), jnp.int32),
     }}
-    with compat.use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jax.jit(lambda p, b: loss_fn(p, b)[0]).lower(
             aparams, batch)
         compiled = lowered.compile()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     print(json.dumps({{"flops": ca["flops"],
                        "devices": len(jax.devices())}}))
 """)
@@ -111,3 +110,12 @@ def test_subprocess_dryrun_host_mesh(arch):
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["devices"] == 8
     assert rec["flops"] > 0
+
+
+def test_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND, peaks
+    v5e = peaks("TPU v5 lite")
+    assert v5e["flops_bf16"] == 197e12 and v5e["hbm_bytes_s"] == 819e9
+    assert peaks(PRODUCTION_DEVICE_KIND) is v5e
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")

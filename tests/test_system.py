@@ -148,3 +148,22 @@ def test_synthetic_data_is_deterministic():
     b1, b2 = next(g1), next(g2)
     np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
     np.testing.assert_array_equal(b1["tokens"][:, 1:], b1["labels"][:, :-1])
+
+
+def test_compile_cache_dir_env_or_fixed_checkout_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set in code;
+    unset, the cache is the fixed .jax_cache inside the checkout."""
+    import pathlib
+    from repro.launch import compile_cache as CC
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert CC.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = pathlib.Path(CC.enable_compile_cache())
+        assert path == CC.DEFAULT_DIR and path.name == ".jax_cache"
+        assert (path.parent / "src" / "repro").is_dir()
+        assert jax.config.jax_compilation_cache_dir == str(path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
