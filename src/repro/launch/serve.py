@@ -94,6 +94,10 @@ reports per-tier acceptance rate and tokens per row-tick:
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
       --reduced --stream --requests 16 --effort balanced,turbo \
       --speculate 4,turbo
+
+Every stream records the scheduler's spans (serving/spans.py): two
+stats lines give host milliseconds per tick by phase and each request's
+queued / prefill / decode p50/p95.
 """
 from __future__ import annotations
 
@@ -101,6 +105,7 @@ import argparse
 import dataclasses
 import functools
 import time
+from typing import Dict, List
 
 import numpy as np
 import jax
@@ -114,7 +119,8 @@ from repro.models.registry import get_model
 from repro.nn.param import init_params
 from repro.serving import (AdmissionController, ContinuousBatchingScheduler,
                            FaultInjector, Request, StaticEngine,
-                           drive_stream, load_trace, parse_speculate_arg)
+                           drive_stream, load_trace, parse_speculate_arg,
+                           spans)
 from repro.serving.runtime import make_runtime
 from repro.serving.trace import trace_stats
 from repro.training.checkpoint import load_checkpoint
@@ -242,6 +248,31 @@ def stream_plans(cfg, requests, *, importance=None, degrade=False,
         for e in names)
 
 
+def span_report(recorded) -> List[str]:
+    """The stream's span stats lines: host milliseconds per tick in each
+    scheduler phase (a phase's spans summed, children included), and
+    per request the p50/p95 of its queued, prefill and decode time."""
+    n_ticks = sum(s[0] == "sched.tick" for s in recorded) or 1
+    phase: Dict[str, float] = {}
+    per_req: Dict[str, Dict[int, float]] = {}
+    for name, start, end, _, args in recorded:
+        if name.startswith("sched."):
+            phase[name] = phase.get(name, 0.0) + end - start
+        else:
+            rids = per_req.setdefault(name, {})
+            rids[args["rid"]] = rids.get(args["rid"], 0.0) + end - start
+    lines = ["spans: host ms per tick | " + " | ".join(
+        f"{k[len('sched.'):]} {1e3 * v / n_ticks:.3f}"
+        for k, v in phase.items()) + f" ({n_ticks} ticks)"]
+    if per_req:
+        ms = {k[len("req."):]: 1e3 * np.percentile(list(v.values()),
+                                                   [50, 95])
+              for k, v in per_req.items()}
+        lines.append("spans: per request p50/p95 ms | " + " | ".join(
+            f"{k} {p50:.1f}/{p95:.1f}" for k, (p50, p95) in ms.items()))
+    return lines
+
+
 def serve_static(cfg, params, args):
     rng = np.random.default_rng(0)
     prompts = make_prompts(cfg, args.requests, args.prompt_len, rng)
@@ -323,7 +354,9 @@ def serve_stream(cfg, params, args):
     counts0 = sched.warmup()
     print(f"warmup done, jit compile counts: {counts0}")
 
+    spans.enable()
     wall = drive_stream(sched, requests)
+    spans.disable()
 
     counts1 = runtime.compile_counts()
     if counts1 != counts0:
@@ -345,6 +378,8 @@ def serve_stream(cfg, params, args):
               f"({len(ttfts)} of {len(outs)} produced a first token)")
     print(f"throughput {gen / wall:8.1f} generated tok/s "
           f"({gen} tokens)")
+    for line in span_report(spans.drain()):
+        print(line)
     # robustness line: terminal-status mix, goodput (deadline-met ok
     # fraction), degradation + fault stats
     n_ok = sum(o.status == "ok" for o in outs.values())
@@ -398,7 +433,8 @@ def serve_stream(cfg, params, args):
         print(f"sparsity[{row['name']}]: keep/layer "
               f"{row['keep_per_layer']} | ffn flop frac "
               f"{row['ffn_flop_frac']:.3f} | {row['prefill_blocks']} "
-              f"prefill blocks, {row['decode_tokens']} decode tokens")
+              f"prefill blocks ({row['sparse_prefill_blocks']} sparse), "
+              f"{row['decode_tokens']} decode tokens")
         if row["attn_flop_frac"] is not None:
             print(f"  attn[{row['name']}]: keep/layer "
                   f"{row['attn_keep_per_layer']} | attn block frac "
@@ -426,8 +462,10 @@ def serve_stream(cfg, params, args):
                   f"({row['emitted']} emitted in {row['row_ticks']} "
                   f"row ticks)")
     print(f"ticks {sched.n_ticks} | prefill blocks "
-          f"{sched.n_prefill_blocks} in {sched.n_prefill_ticks} prefill "
-          f"ticks (P<={sched.prefill_batch}) | decode steps "
+          f"{sched.n_prefill_blocks} (dense {sched.n_prefill_rows_dense}, "
+          f"sparse {sched.n_prefill_rows_sparse}) in "
+          f"{sched.n_prefill_ticks} prefill ticks "
+          f"(P<={sched.prefill_batch}) | decode steps "
           f"{sched.n_decode_steps}")
     if args.eos_id is not None:
         print(f"eos_id={args.eos_id}: {sched.n_eos_stops} of {len(outs)} "

@@ -92,6 +92,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.serving import spans
 from repro.serving.admission import AdmissionController
 from repro.serving.cache_pool import KVSlotPool
 from repro.serving.kv_tier import HostKVTier
@@ -132,6 +133,9 @@ class Request:
     # degradation decision sticks, so preemption re-admits under the
     # SAME tier and stays output-transparent). Not a user field.
     assigned_plan_idx: Optional[int] = None
+    # scheduler-internal: start of the current queued interval (arrival,
+    # or the preemption that requeued it) — the `req.queued` span's.
+    queued_at: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -174,6 +178,7 @@ class _ActiveState:
     out: List[int] = dataclasses.field(default_factory=list)
     next_token: int = 0          # last sampled token (decode input)
     pos: int = 0                 # next KV write position
+    admitted_at: Optional[float] = None
     first_token_time: Optional[float] = None
     # prefix sharing: the prompt's page-aligned token tuples (None when
     # the cache is off) — lookup happens at admission, publish per
@@ -282,6 +287,7 @@ class ContinuousBatchingScheduler:
         self.plan_index = dict(getattr(runtime, "plan_index", {}) or {})
         n_plans = max(len(self.plans), 1)
         self.plan_prefill_blocks = np.zeros(n_plans, np.int64)
+        self.plan_prefill_sparse_rows = np.zeros(n_plans, np.int64)
         self.plan_decode_tokens = np.zeros(n_plans, np.int64)
         # self-speculative decoding (serving/speculative.py): drafts
         # come from the SAME weights under the named (sparser) plan, so
@@ -333,6 +339,11 @@ class ContinuousBatchingScheduler:
         self.n_prefill_blocks = 0
         self.n_prefill_ticks = 0
         self.n_decode_steps = 0
+        # prefill rows run by FFN branch: dense (a first or last prompt
+        # block) or sparse (per plan below), so the realized FFN FLOP
+        # fraction counts each at what it computed
+        self.n_prefill_rows_dense = 0
+        self.n_prefill_rows_sparse = 0
         self.n_eos_stops = 0
         self.n_preemptions = 0
         # robustness counters (terminal statuses + degradation)
@@ -355,13 +366,18 @@ class ContinuousBatchingScheduler:
     # --------------------------------------------------------- submit
 
     def submit(self, req: Request) -> None:
-        """Validate and enqueue. Malformed requests (empty prompt,
-        max_new < 1, unknown effort) are CALLER bugs and still raise;
-        a well-formed request the pool can never hold, or that provably
-        cannot meet its deadline, is SHED instead — it finishes
+        """Validate and enqueue (span `sched.submit`). Malformed
+        requests (empty prompt, max_new < 1, unknown effort) are CALLER
+        bugs and still raise; a well-formed request the pool can never
+        hold, or that provably cannot meet its deadline, is SHED
+        instead — it finishes
         immediately with status="shed" and a reason, so one oversized
         request in a stream no longer kills the whole replay (and can
         never livelock admission waiting for pages that cannot exist)."""
+        with spans.span("sched.submit", rid=req.rid):
+            self._submit(req)
+
+    def _submit(self, req: Request) -> None:
         if not req.prompt:
             raise ValueError(f"request {req.rid} has an empty prompt")
         if req.max_new < 1:
@@ -401,6 +417,7 @@ class ContinuousBatchingScheduler:
         if reason is not None:
             self._finish_queued(req, "shed", reason)
             return
+        req.queued_at = req.arrival_time
         self.queue.append(req)
 
     def _n_blocks(self, req: Request) -> int:
@@ -452,15 +469,26 @@ class ContinuousBatchingScheduler:
         queued), admit (with degradation), prefill, decode, and
         finally the stall watchdog — `stall_ticks` consecutive ticks
         with pending work and no observable progress raise
-        `SchedulerStallError` with a full state dump."""
+        `SchedulerStallError` with a full state dump.
+
+        Spans (serving/spans.py): `sched.tick`, numbered by `n_ticks`,
+        holds `sched.admit`, then per phase `sched.prefill.build` /
+        `sched.decode.build` (batch and host arrays), `.dispatch` (the
+        runtime call), `sched.readback` (host reads of device output)
+        and `sched.emit` (tokens, finishes, the watchdog)."""
         self.n_ticks += 1
-        if self.faults is not None:
-            self.faults.on_tick(self)
-        if self.admission is not None:
-            self.admission.observe(len(self.queue), self._free_frac())
-        self._expire_deadlines()
-        self._resume_swapped()
-        self._admit()
+        with spans.step("sched.tick", self.n_ticks, self.clock):
+            return self._tick()
+
+    def _tick(self) -> int:
+        with spans.span("sched.admit"):
+            if self.faults is not None:
+                self.faults.on_tick(self)
+            if self.admission is not None:
+                self.admission.observe(len(self.queue), self._free_frac())
+            self._expire_deadlines()
+            self._resume_swapped()
+            self._admit()
         t0 = self.clock()
         before = self.n_prefill_ticks
         emitted = self._prefill_blocks()
@@ -480,7 +508,8 @@ class ContinuousBatchingScheduler:
         emitted += self._decode_all()
         self.pool.note_tick()
         self._total_emitted += emitted
-        self._watchdog()
+        with spans.span("sched.emit"):
+            self._watchdog()
         return emitted
 
     def _free_frac(self) -> float:
@@ -685,6 +714,7 @@ class ContinuousBatchingScheduler:
         self._admit_seq = 0
         self.n_ticks = self.n_prefill_blocks = self.n_decode_steps = 0
         self.n_prefill_ticks = self.n_eos_stops = 0
+        self.n_prefill_rows_dense = self.n_prefill_rows_sparse = 0
         self.n_preemptions = 0
         self.n_shed = self.n_timed_out = self.n_cancelled = 0
         self.n_degraded = 0
@@ -695,6 +725,7 @@ class ContinuousBatchingScheduler:
             self.admission.reset()
         self.faults = faults
         self.plan_prefill_blocks[:] = 0
+        self.plan_prefill_sparse_rows[:] = 0
         self.plan_decode_tokens[:] = 0
         self.n_spec_ticks = 0
         self.spec_row_ticks[:] = 0
@@ -822,6 +853,8 @@ class ContinuousBatchingScheduler:
                 if plan_idx != self.plan_index.get(req.effort, 0):
                     self.n_degraded += 1
                 req.assigned_plan_idx = plan_idx
+            now = self.clock()
+            spans.emit("req.queued", req.queued_at, now, rid=req.rid)
             st = _ActiveState(
                 req=req, slot=slot, seq=self._admit_seq,
                 n_blocks=self._n_blocks(req),
@@ -830,7 +863,7 @@ class ContinuousBatchingScheduler:
                 # entries (the warmup throwaway request carries rid=-1)
                 rng=np.random.default_rng(
                     (self.seed, req.rid % (1 << 32))),
-                page_keys=keys)
+                admitted_at=now, page_keys=keys)
             self.active[slot] = st
             self._admit_seq += 1
             if self.prefix_index is not None:
@@ -908,6 +941,8 @@ class ContinuousBatchingScheduler:
         """Terminal state for a request that never held resources
         (shed at submit, expired/cancelled while queued)."""
         now = self.clock()
+        if req.queued_at is not None:
+            spans.emit("req.queued", req.queued_at, now, rid=req.rid)
         arrival = (req.arrival_time if req.arrival_time is not None
                    else now)
         self.finished[req.rid] = RequestOutput(
@@ -923,6 +958,9 @@ class ContinuousBatchingScheduler:
         paged — every page, idempotently (the pool guards double
         release)."""
         now = self.clock()
+        if st.first_token_time is not None:
+            spans.emit("req.decode", st.first_token_time, now,
+                       rid=st.req.rid)
         self.finished[st.req.rid] = RequestOutput(
             rid=st.req.rid, tokens=list(st.out),
             prompt_len=len(st.req.prompt),
@@ -1014,6 +1052,7 @@ class ContinuousBatchingScheduler:
         elif self.parked.get(st.slot) is st:
             del self.parked[st.slot]
         self.pool.release(st.slot)
+        st.req.queued_at = self.clock()
         self.queue.appendleft(st.req)
         self.n_preemptions += 1
 
@@ -1053,9 +1092,9 @@ class ContinuousBatchingScheduler:
             chunk = pages[i:i + W]
             ids = np.zeros(W, np.int32)
             ids[:len(chunk)] = chunk
-            got = jax.tree.map(np.asarray,
-                               self.runtime.read_pages(self.pool.cache,
-                                                       ids))
+            pages_dev = self.runtime.read_pages(self.pool.cache, ids)
+            with spans.span("sched.readback", kind="swap"):
+                got = jax.tree.map(np.asarray, pages_dev)
             for j in range(len(chunk)):
                 payloads.append(jax.tree.map(lambda a: a[:, j].copy(),
                                              got))
@@ -1185,6 +1224,8 @@ class ContinuousBatchingScheduler:
             return 0
         tok = self._sample(logits_row(), st)
         st.first_token_time = self.clock()
+        spans.emit("req.prefill", st.admitted_at, st.first_token_time,
+                   rid=st.req.rid)
         st.out.append(tok)
         st.next_token = tok
         st.pos = len(st.req.prompt)
@@ -1192,37 +1233,45 @@ class ContinuousBatchingScheduler:
         self._maybe_finish(st)
         return 1
 
-    def _prefill_one_block(self, st: _ActiveState, meta) -> int:
-        """Original one-block-per-tick path (PR-1): one request, one
-        [1, N] jitted call. Kept as the prefill_batch=1 / width-1 bucket
-        the batched path is benchmarked and bit-compared against. In
-        the paged layout this is the width-1 `prefill_blocks_paged`
-        bucket (there is no separate single-request paged entry).
-        `meta` is the state's precomputed `_block_meta` for this tick."""
-        N = self.runtime.block_size
-        chunk, pos0, is_dense = meta
-        tok_blk = np.zeros((1, N), np.int32)
-        tok_blk[0, :len(chunk)] = chunk
-        plan = self._plan_of(st)
-        if self.paged:
-            self.pool.cache, logits = self.runtime.prefill_blocks_paged(
-                self.pool.cache, tok_blk,
-                self.pool.page_table[st.slot][None],
-                np.array([pos0], np.int32), np.array([is_dense], bool),
-                np.array([len(st.req.prompt)], np.int32),
-                np.ones(1, bool), plan=plan)
-            self.n_prefill_ticks += 1
-            return self._finish_block(st, lambda: np.asarray(logits)[0])
-        self.pool.cache, logits = self.runtime.prefill_block(
-            self.pool.cache, tok_blk, st.slot, pos0, is_dense,
-            len(st.req.prompt), plan=plan)
-        self.n_prefill_ticks += 1
-        return self._finish_block(st, lambda: np.asarray(logits))
-
     def _prefill_blocks(self) -> int:
-        """Batched prefill: drain one block of EACH of up to
-        `prefill_batch` distinct prefilling requests (oldest first) in
-        one jitted `prefill_blocks` call.
+        """Batched prefill: one block of EACH of up to `prefill_batch`
+        distinct prefilling requests (oldest first, `_prefill_batch`) in
+        one jitted call (`_prefill_inputs`); the logits cross to the
+        host only when some row's block was its last."""
+        with spans.span("sched.prefill.build"):
+            batch = self._prefill_batch()
+            if batch:
+                entry, args, plan = self._prefill_inputs(batch)
+        if not batch:
+            return 0
+        with spans.span("sched.prefill.dispatch"):
+            self.pool.cache, logits = entry(self.pool.cache, *args,
+                                            plan=plan)
+        self.n_prefill_ticks += 1
+        lead, (_, _, dense) = batch[0]
+        if dense:
+            self.n_prefill_rows_dense += len(batch)
+        else:
+            self.n_prefill_rows_sparse += len(batch)
+            self.plan_prefill_sparse_rows[lead.plan_idx] += len(batch)
+        host = []                 # [rows, V], pulled at most once
+
+        def row(i):
+            def get():
+                if not host:
+                    with spans.span("sched.readback", kind="prefill"):
+                        out = np.asarray(logits)
+                    host.append(out.reshape(-1, out.shape[-1]))
+                return host[0][i]
+            return get
+
+        with spans.span("sched.emit"):
+            return sum(self._finish_block(st, row(i))
+                       for i, (st, _) in enumerate(batch))
+
+    def _prefill_batch(self) -> list:
+        """[(state, _block_meta)] of the states whose next block this
+        tick's prefill call runs, oldest first.
 
         Two batch-shaping policies keep the batched tick cheap:
 
@@ -1233,17 +1282,14 @@ class ContinuousBatchingScheduler:
             then all-equal and `ff_blocks_sparse`'s any()-gated conds
             execute exactly ONE branch — a mixed batch would pay for
             both;
-          * width bucketing — the batch is padded up to the smallest
-            pre-compiled width bucket (not always to P) with inactive
-            rows parked on slot ids unused by this call's live rows
-            (their KV writes are discarded device-side), so a backlog
-            of 1-2 requests doesn't pay a P-wide padded call.
+          * width bucketing (`_prefill_inputs`) — the batch is padded
+            up to the smallest pre-compiled width bucket (not always to
+            P), so a backlog of 1-2 requests doesn't pay a P-wide
+            padded call.
         """
         states = sorted(
             (s for s in self.active.values() if s.phase == "prefill"),
             key=lambda s: s.seq)                        # FIFO
-        if not states:
-            return 0
         # one _block_meta per state per tick: the same meta drives both
         # the density filter and the batch fill (re-deriving it would
         # re-slice each prompt chunk). In the paged layout each
@@ -1275,12 +1321,31 @@ class ContinuousBatchingScheduler:
                 lead_dense = meta[2]
                 lead_plan = st.plan_idx
             batch.append((st, meta))
-        if not batch:
-            return 0
-        if len(batch) == 1:
-            return self._prefill_one_block(*batch[0])   # width-1 bucket
-        P = next(w for w in self.prefill_widths if w >= len(batch))
+        return batch
+
+    def _prefill_inputs(self, batch: list):
+        """(runtime entry, its arguments after the cache, plan) of the
+        one call that runs `batch`. A lone row in the slot layout takes
+        the original one-block-per-tick `prefill_block` entry (PR-1:
+        one request, one [1, N] call — the prefill_batch=1 baseline the
+        batched path is benchmarked and bit-compared against); the
+        paged layout has no single-request entry and uses the width-1
+        `prefill_blocks_paged` bucket. Otherwise the rows are padded up
+        to the smallest width bucket holding them: inactive rows park
+        on slot ids unused by this call's live rows (their KV writes
+        are discarded device-side) or, paged, carry all-null tables
+        (write-sink self-copies)."""
         N = self.runtime.block_size
+        lead, (_, _, lead_dense) = batch[0]
+        plan = self._plan_of(lead)
+        if len(batch) == 1 and not self.paged:
+            chunk, pos0, is_dense = batch[0][1]
+            tok_blk = np.zeros((1, N), np.int32)
+            tok_blk[0, :len(chunk)] = chunk
+            return (self.runtime.prefill_block,
+                    (tok_blk, lead.slot, pos0, is_dense,
+                     len(lead.req.prompt)), plan)
+        P = next(w for w in self.prefill_widths if w >= len(batch))
         tokens = np.zeros((P, N), np.int32)
         slots = np.zeros(P, np.int32)
         pos0s = np.zeros(P, np.int32)
@@ -1293,39 +1358,62 @@ class ContinuousBatchingScheduler:
             pos0s[i] = pos0
             lengths[i] = len(st.req.prompt)
             active[i] = True
-        plan = self.plans[lead_plan] if self.plans else None
         if self.paged:
-            # pad rows carry all-null tables (write-sink self-copies)
             tables = np.zeros((P, self.pool.max_pages), np.int32)
             for i, (st, _) in enumerate(batch):
                 tables[i] = self.pool.page_table[st.slot]
-            self.pool.cache, logits = self.runtime.prefill_blocks_paged(
-                self.pool.cache, tokens, tables, pos0s, is_dense,
-                lengths, active, plan=plan)
-        else:
-            used = {st.slot for st, _ in batch}
-            spare = (s for s in range(self.n_slots) if s not in used)
-            for i in range(len(batch), P):
-                slots[i] = next(spare)
-            self.pool.cache, logits = self.runtime.prefill_blocks(
-                self.pool.cache, tokens, slots, pos0s, is_dense, lengths,
-                active, plan=plan)
-        self.n_prefill_ticks += 1
-        logits_np = [None]        # pull [P, V] to host at most once
-
-        def row(i):
-            def get():
-                if logits_np[0] is None:
-                    logits_np[0] = np.asarray(logits)
-                return logits_np[0][i]
-            return get
-
-        return sum(self._finish_block(st, row(i))
-                   for i, (st, _) in enumerate(batch))
+            return (self.runtime.prefill_blocks_paged,
+                    (tokens, tables, pos0s, is_dense, lengths, active),
+                    plan)
+        used = {st.slot for st, _ in batch}
+        spare = (s for s in range(self.n_slots) if s not in used)
+        for i in range(len(batch), P):
+            slots[i] = next(spare)
+        return (self.runtime.prefill_blocks,
+                (tokens, slots, pos0s, is_dense, lengths, active), plan)
 
     def _decode_all(self) -> int:
         if self.speculative is not None and self.speculative.k > 0:
             return self._decode_all_speculative()
+        with spans.span("sched.decode.build"):
+            call = self._decode_inputs()
+        if call is None:
+            return 0
+        decoding, tokens, positions, active, plan_ids = call
+        with spans.span("sched.decode.dispatch"):
+            if self.paged:
+                logits, greedy, self.pool.cache = \
+                    self.runtime.decode_step_paged(
+                        self.pool.cache, tokens, self.pool.page_table,
+                        positions, active, plan_ids=plan_ids)
+            else:
+                logits, greedy, self.pool.cache = self.runtime.decode_step(
+                    self.pool.cache, tokens, positions, active,
+                    plan_ids=plan_ids)
+        self.n_decode_steps += 1
+        with spans.span("sched.readback", kind="decode"):
+            greedy = np.asarray(greedy)
+            # logits cross to host only if someone actually samples
+            logits_np = (np.asarray(logits)
+                         if any(s.req.temperature > 0 for s in decoding)
+                         else None)
+        emitted = 0
+        with spans.span("sched.emit"):
+            for st in decoding:
+                tok = (int(greedy[st.slot]) if st.req.temperature <= 0
+                       else self._sample(logits_np[st.slot], st))
+                st.out.append(tok)
+                st.next_token = tok
+                st.pos += 1
+                self.pool.lengths[st.slot] = st.pos
+                self.plan_decode_tokens[st.plan_idx] += 1
+                emitted += 1
+                self._maybe_finish(st)
+        return emitted
+
+    def _decode_inputs(self):
+        """(decoding states, tokens, positions, active, plan_ids) of
+        this tick's batched decode step; None when no row decodes."""
         decoding = [s for s in self.active.values() if s.phase == "decode"]
         if self.paged:
             # each decoding row must own the page covering its write
@@ -1343,7 +1431,7 @@ class ContinuousBatchingScheduler:
                 ready.append(st)
             decoding = ready
         if not decoding:
-            return 0
+            return None
         tokens = np.zeros(self.n_slots, np.int32)
         positions = np.zeros(self.n_slots, np.int32)
         active = np.zeros(self.n_slots, bool)
@@ -1353,32 +1441,7 @@ class ContinuousBatchingScheduler:
             positions[st.slot] = st.pos
             active[st.slot] = True
             plan_ids[st.slot] = st.plan_idx
-        if self.paged:
-            logits, greedy, self.pool.cache = self.runtime.decode_step_paged(
-                self.pool.cache, tokens, self.pool.page_table, positions,
-                active, plan_ids=plan_ids)
-        else:
-            logits, greedy, self.pool.cache = self.runtime.decode_step(
-                self.pool.cache, tokens, positions, active,
-                plan_ids=plan_ids)
-        self.n_decode_steps += 1
-        greedy = np.asarray(greedy)
-        # logits cross to host only if someone actually samples
-        logits_np = (np.asarray(logits)
-                     if any(s.req.temperature > 0 for s in decoding)
-                     else None)
-        emitted = 0
-        for st in decoding:
-            tok = (int(greedy[st.slot]) if st.req.temperature <= 0
-                   else self._sample(logits_np[st.slot], st))
-            st.out.append(tok)
-            st.next_token = tok
-            st.pos += 1
-            self.pool.lengths[st.slot] = st.pos
-            self.plan_decode_tokens[st.plan_idx] += 1
-            emitted += 1
-            self._maybe_finish(st)
-        return emitted
+        return decoding, tokens, positions, active, plan_ids
 
     def _spec_draft_limit(self, st: _ActiveState) -> int:
         """How many tokens this row may draft THIS tick (0 .. k):
@@ -1413,104 +1476,116 @@ class ContinuousBatchingScheduler:
         pages are always exclusively-owned decode growth: published
         prefix pages cover only pre-last-block prompt positions
         (< prompt_len <= pos), so truncation can never touch them."""
-        k = self.speculative.k
-        psz = self.pool.page_size if self.paged else 0
-        decoding = []
-        n_draft = np.zeros(self.n_slots, np.int32)
-        for st in sorted((s for s in self.active.values()
-                          if s.phase == "decode"), key=lambda s: s.seq):
-            if self.active.get(st.slot) is not st:
-                continue               # preempted by an earlier row's grow
-            lim = self._spec_draft_limit(st)
-            if self.paged:
-                # base coverage (the committed token's page) may evict/
-                # preempt exactly like the non-speculative tick; the
-                # SPECULATIVE extra pages are only taken from the free
-                # heap — never preempting live work just to draft
-                if not self._ensure_pages(st, st.pos // psz + 1):
-                    continue           # stalled this tick, retried
-                while lim > 0 and not self.pool.ensure(
-                        st.slot, (st.pos + lim) // psz + 1):
-                    lim -= 1
-            n_draft[st.slot] = lim
-            decoding.append(st)
-        if not decoding:
-            return 0
-        tokens = np.zeros(self.n_slots, np.int32)
-        positions = np.zeros(self.n_slots, np.int32)
-        active = np.zeros(self.n_slots, bool)
-        verify_ids = np.zeros(self.n_slots, np.int32)
-        draft_ids = np.zeros(self.n_slots, np.int32)
-        for st in decoding:
-            tokens[st.slot] = st.next_token
-            positions[st.slot] = st.pos
-            active[st.slot] = True
-            verify_ids[st.slot] = st.plan_idx
-            draft_ids[st.slot] = self._draft_plan_for[st.plan_idx]
-        chunk = np.zeros((self.n_slots, k + 1), np.int32)
-        chunk[:, 0] = tokens
+        with spans.span("sched.decode.build"):
+            k = self.speculative.k
+            psz = self.pool.page_size if self.paged else 0
+            decoding = []
+            n_draft = np.zeros(self.n_slots, np.int32)
+            for st in sorted((s for s in self.active.values()
+                              if s.phase == "decode"), key=lambda s: s.seq):
+                if self.active.get(st.slot) is not st:
+                    continue       # preempted by an earlier row's grow
+                lim = self._spec_draft_limit(st)
+                if self.paged:
+                    # base coverage (the committed token's page) may
+                    # evict/preempt exactly like the non-speculative
+                    # tick; the SPECULATIVE extra pages are only taken
+                    # from the free heap — never preempting live work
+                    # just to draft
+                    if not self._ensure_pages(st, st.pos // psz + 1):
+                        continue           # stalled this tick, retried
+                    while lim > 0 and not self.pool.ensure(
+                            st.slot, (st.pos + lim) // psz + 1):
+                        lim -= 1
+                n_draft[st.slot] = lim
+                decoding.append(st)
+            if not decoding:
+                return 0
+            tokens = np.zeros(self.n_slots, np.int32)
+            positions = np.zeros(self.n_slots, np.int32)
+            active = np.zeros(self.n_slots, bool)
+            verify_ids = np.zeros(self.n_slots, np.int32)
+            draft_ids = np.zeros(self.n_slots, np.int32)
+            for st in decoding:
+                tokens[st.slot] = st.next_token
+                positions[st.slot] = st.pos
+                active[st.slot] = True
+                verify_ids[st.slot] = st.plan_idx
+                draft_ids[st.slot] = self._draft_plan_for[st.plan_idx]
+            chunk = np.zeros((self.n_slots, k + 1), np.int32)
+            chunk[:, 0] = tokens
         if int(n_draft.max()) > 0:
+            with spans.span("sched.decode.dispatch"):
+                if self.paged:
+                    drafts, self.pool.cache = \
+                        self.runtime.draft_steps_paged(
+                            self.pool.cache, tokens, self.pool.page_table,
+                            positions, active, n_draft, k,
+                            plan_ids=draft_ids)
+                else:
+                    drafts, self.pool.cache = self.runtime.draft_steps(
+                        self.pool.cache, tokens, positions, active,
+                        n_draft, k, plan_ids=draft_ids)
+            with spans.span("sched.readback", kind="decode"):
+                chunk[:, 1:] = np.asarray(drafts)
+        with spans.span("sched.decode.dispatch"):
             if self.paged:
-                drafts, self.pool.cache = self.runtime.draft_steps_paged(
-                    self.pool.cache, tokens, self.pool.page_table,
-                    positions, active, n_draft, k, plan_ids=draft_ids)
+                logits0, greedy, self.pool.cache = \
+                    self.runtime.verify_chunk_paged(
+                        self.pool.cache, chunk, self.pool.page_table,
+                        positions, active, n_draft + 1,
+                        plan_ids=verify_ids)
             else:
-                drafts, self.pool.cache = self.runtime.draft_steps(
-                    self.pool.cache, tokens, positions, active, n_draft,
-                    k, plan_ids=draft_ids)
-            chunk[:, 1:] = np.asarray(drafts)
-        if self.paged:
-            logits0, greedy, self.pool.cache = self.runtime.verify_chunk_paged(
-                self.pool.cache, chunk, self.pool.page_table, positions,
-                active, n_draft + 1, plan_ids=verify_ids)
-        else:
-            logits0, greedy, self.pool.cache = self.runtime.verify_chunk(
-                self.pool.cache, chunk, positions, active, n_draft + 1,
-                plan_ids=verify_ids)
+                logits0, greedy, self.pool.cache = self.runtime.verify_chunk(
+                    self.pool.cache, chunk, positions, active, n_draft + 1,
+                    plan_ids=verify_ids)
         self.n_decode_steps += 1
         self.n_spec_ticks += 1
-        greedy = np.asarray(greedy)
-        logits0_np = (np.asarray(logits0)
-                      if any(s.req.temperature > 0 for s in decoding)
-                      else None)
+        with spans.span("sched.readback", kind="decode"):
+            greedy = np.asarray(greedy)
+            logits0_np = (np.asarray(logits0)
+                          if any(s.req.temperature > 0 for s in decoding)
+                          else None)
         emitted = 0
-        for st in decoding:
-            nd = int(n_draft[st.slot])
-            if st.req.temperature > 0:
-                # exact non-speculative sampling tick: nd == 0, and the
-                # chunk's step-0 logits are the decode_step logits
-                toks = [self._sample(logits0_np[st.slot], st)]
-            else:
-                n_acc, accepted = accept_drafts(
-                    chunk[st.slot, 1:], greedy[st.slot], nd)
-                toks = [int(t) for t in accepted]
-                self.spec_row_ticks[st.plan_idx] += 1
-                self.spec_drafted[st.plan_idx] += nd
-                self.spec_accepted[st.plan_idx] += n_acc
-            row_emitted = 0
-            for tok in toks:
-                st.out.append(tok)
-                st.next_token = tok
-                st.pos += 1
-                self.pool.lengths[st.slot] = st.pos
-                self.plan_decode_tokens[st.plan_idx] += 1
-                row_emitted += 1
-                self._maybe_finish(st)
-                if self.active.get(st.slot) is not st:
-                    break   # EOS/max_new released the slot (and, paged,
-                    #         every page) — nothing left to roll back
-            if st.req.temperature <= 0:
-                self.spec_emitted[st.plan_idx] += row_emitted
-            emitted += row_emitted
-            if self.paged and self.active.get(st.slot) is st:
-                # truncate tail pages past the accepted position: pages
-                # the rejected drafts grew go back to the free heap with
-                # exact alloc/free accounting. st.pos >= 1 always
-                # (decode starts at pos = prompt_len >= 1).
-                keep = (st.pos - 1) // psz + 1
-                trim = int(self.pool.allocated[st.slot]) - keep
-                if trim > 0:
-                    self.pool.unmap_tail(st.slot, trim)
+        with spans.span("sched.emit"):
+            for st in decoding:
+                nd = int(n_draft[st.slot])
+                if st.req.temperature > 0:
+                    # exact non-speculative sampling tick: nd == 0, and
+                    # the chunk's step-0 logits are the decode_step logits
+                    toks = [self._sample(logits0_np[st.slot], st)]
+                else:
+                    n_acc, accepted = accept_drafts(
+                        chunk[st.slot, 1:], greedy[st.slot], nd)
+                    toks = [int(t) for t in accepted]
+                    self.spec_row_ticks[st.plan_idx] += 1
+                    self.spec_drafted[st.plan_idx] += nd
+                    self.spec_accepted[st.plan_idx] += n_acc
+                row_emitted = 0
+                for tok in toks:
+                    st.out.append(tok)
+                    st.next_token = tok
+                    st.pos += 1
+                    self.pool.lengths[st.slot] = st.pos
+                    self.plan_decode_tokens[st.plan_idx] += 1
+                    row_emitted += 1
+                    self._maybe_finish(st)
+                    if self.active.get(st.slot) is not st:
+                        break   # EOS/max_new released the slot (and,
+                        #         paged, every page) — nothing left to
+                        #         roll back
+                if st.req.temperature <= 0:
+                    self.spec_emitted[st.plan_idx] += row_emitted
+                emitted += row_emitted
+                if self.paged and self.active.get(st.slot) is st:
+                    # truncate tail pages past the accepted position:
+                    # pages the rejected drafts grew go back to the free
+                    # heap with exact alloc/free accounting. st.pos >= 1
+                    # always (decode starts at pos = prompt_len >= 1).
+                    keep = (st.pos - 1) // psz + 1
+                    trim = int(self.pool.allocated[st.slot]) - keep
+                    if trim > 0:
+                        self.pool.unmap_tail(st.slot, trim)
         return emitted
 
     # ----------------------------------------------------- plan stats
@@ -1518,9 +1593,11 @@ class ContinuousBatchingScheduler:
     def sparsity_stats(self) -> dict:
         """Realized sparsity accounting (serve.py stats line): per
         registered plan, the per-layer keep fractions, analytical FFN
-        FLOP fraction, and how much work (prefill blocks / decode
-        tokens) actually ran under it; plus the work-weighted aggregate
-        FFN FLOP fraction of the whole stream."""
+        FLOP fraction, and how much work (prefill blocks, the sparse
+        ones among them, decode tokens) actually ran under it; plus the
+        work-weighted aggregates of the whole stream. In the FFN's, a
+        dense prefill block (a prompt's first or last) counts at 1.0,
+        sparse blocks and decode tokens at their plan's fraction."""
         N = self.runtime.block_size
         out = {"plans": [], "aggregate_ffn_flop_frac": None,
                "aggregate_attn_flop_frac": None}
@@ -1528,6 +1605,8 @@ class ContinuousBatchingScheduler:
             return out
         weights = (self.plan_prefill_blocks * N
                    + self.plan_decode_tokens).astype(np.float64)
+        sparse = (self.plan_prefill_sparse_rows * N
+                  + self.plan_decode_tokens).astype(np.float64)
         fracs = np.array([p.flop_frac() for p in self.plans])
         # dual-budget plans also carry an attention-block budget; plans
         # without one run dense attention (fraction 1.0)
@@ -1535,7 +1614,8 @@ class ContinuousBatchingScheduler:
                            for p in self.plans])
         if weights.sum() > 0:
             out["aggregate_ffn_flop_frac"] = float(
-                (weights * fracs).sum() / weights.sum())
+                (self.n_prefill_rows_dense * N + (sparse * fracs).sum())
+                / weights.sum())
             out["aggregate_attn_flop_frac"] = float(
                 (weights * afracs).sum() / weights.sum())
         for i, p in enumerate(self.plans):
@@ -1550,6 +1630,8 @@ class ContinuousBatchingScheduler:
                 "attn_flop_frac": (round(p.attn_flop_frac(), 4)
                                    if p.has_attn else None),
                 "prefill_blocks": int(self.plan_prefill_blocks[i]),
+                "sparse_prefill_blocks": int(
+                    self.plan_prefill_sparse_rows[i]),
                 "decode_tokens": int(self.plan_decode_tokens[i]),
             })
         return out
@@ -1623,6 +1705,7 @@ class ContinuousBatchingScheduler:
         if hit_eos and len(st.out) < st.req.max_new:
             self.n_eos_stops += 1     # early exit frees the slot now
         now = self.clock()
+        spans.emit("req.decode", st.first_token_time, now, rid=st.req.rid)
         self.finished[st.req.rid] = RequestOutput(
             rid=st.req.rid, tokens=list(st.out),
             prompt_len=len(st.req.prompt),

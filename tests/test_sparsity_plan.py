@@ -324,8 +324,20 @@ def test_mixed_effort_stream_compile_flat(dense_setup, kv_layout):
     stats = sched.sparsity_stats()
     assert [p["name"] for p in stats["plans"]] == ["balanced", "turbo"]
     assert all(p["prefill_blocks"] > 0 for p in stats["plans"])
-    frac = stats["aggregate_ffn_flop_frac"]
-    assert plans[1].flop_frac() < frac < plans[0].flop_frac()
+    # dense first/last prompt blocks count at 1.0, sparse blocks and
+    # decode tokens at their plan's fraction
+    N = runtime.block_size
+    n_dense, n_sparse = sched.n_prefill_rows_dense, sched.n_prefill_rows_sparse
+    assert n_dense > 0 and n_sparse > 0
+    assert n_dense + n_sparse == sched.n_prefill_blocks
+    rows = stats["plans"]
+    assert sum(p["sparse_prefill_blocks"] for p in rows) == n_sparse
+    done = N * n_dense + sum(
+        p.flop_frac() * (N * r["sparse_prefill_blocks"] + r["decode_tokens"])
+        for p, r in zip(plans, rows))
+    work = N * (n_dense + n_sparse) + sum(r["decode_tokens"] for r in rows)
+    assert stats["aggregate_ffn_flop_frac"] == pytest.approx(done / work,
+                                                             rel=1e-12)
 
 
 def test_effort_output_independent_of_batch_mix(dense_setup):
