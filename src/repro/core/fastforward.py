@@ -113,8 +113,10 @@ def _compensate(params, cfg: ModelConfig, x, y):
     return y
 
 
-def ff_dense(params, cfg: ModelConfig, x):
-    return S.ffn_dense(params, x, cfg.act)
+def ff_dense(params, cfg: ModelConfig, x, layer=None):
+    """Dense FFN; with `layer`, params' matrices are the stacked [L, ...]
+    leaves and layer `layer` is computed (`S.ffn_at`)."""
+    return S.ffn_dense(S.ffn_at(params, layer), x, cfg.act)
 
 
 # ------------------------------------------------- full-sequence (mask)
@@ -166,7 +168,8 @@ def ff_masked_sequence(params, cfg: ModelConfig, x, keep_frac,
 
 
 def ff_block_sparse(params, cfg: ModelConfig, x_block, plan,
-                    shards: int = 1, is_dense=None, k_valid=None):
+                    shards: int = 1, is_dense=None, k_valid=None,
+                    layer=None):
     """Gather path for one prompt block: x_block [B, N, D] -> [B, N, D].
 
     plan: SparsityPlan (static — its k_max is the jit tile-id width; a
@@ -179,10 +182,17 @@ def ff_block_sparse(params, cfg: ModelConfig, x_block, plan,
     blockwise-prefill scan. A [B] is_dense VECTOR (rows from distinct
     requests, each at its own boundary) delegates to the per-row
     `ff_blocks_sparse` path.
+
+    layer: optional traced int32 — params' W_gate/W_up/W_down are the
+    model's stacked [L, ...] leaves (predictor and compensator stay one
+    layer's) and this is the layer to compute: the sparse kernel reads
+    its tiles out of the stacks in place, the dense branch takes the
+    layer's slices inside its cond (`S.ffn_at`). None: one layer's
+    params throughout.
     """
     if is_dense is not None and jnp.ndim(is_dense) == 1:
         return ff_blocks_sparse(params, cfg, x_block, plan, shards,
-                                is_dense, k_valid=k_valid)
+                                is_dense, k_valid=k_valid, layer=layer)
     ff = cfg.ff
     plan = _as_plan(cfg, plan, shards=shards)
     sel_shards = 1 if k_valid is not None else shards
@@ -192,18 +202,19 @@ def ff_block_sparse(params, cfg: ModelConfig, x_block, plan,
 
     def sparse(x):
         y = S.ffn_sparse_batched(params, x, ids, ff.tile, cfg.act,
-                                 k_valid=k_valid)
+                                 k_valid=k_valid, layer=layer)
         return _compensate(params, cfg, x, y)
 
     if is_dense is None:
         return sparse(x_block)
     return jax.lax.cond(is_dense,
-                        lambda x: S.ffn_dense(params, x, cfg.act),
+                        lambda x: ff_dense(params, cfg, x, layer),
                         sparse, x_block)
 
 
 def ff_blocks_sparse(params, cfg: ModelConfig, x_blocks, plan,
-                     shards: int = 1, is_dense=None, k_valid=None):
+                     shards: int = 1, is_dense=None, k_valid=None,
+                     layer=None):
     """Gather path for a batch of blocks from DISTINCT requests with
     per-row dense forcing: x_blocks [P, N, D], is_dense [P] bool.
 
@@ -216,7 +227,8 @@ def ff_blocks_sparse(params, cfg: ModelConfig, x_blocks, plan,
     its batchmates stay sparse. Each path runs under a `lax.cond` on
     whether ANY row needs it, so an all-sparse steady-state batch never
     pays dense FLOPs (and an all-dense batch skips predictor + gather).
-    The compensator fires only on sparse rows.
+    The compensator fires only on sparse rows. `layer`: stacked FFN
+    matrices, as in `ff_block_sparse`.
     """
     ff = cfg.ff
     plan = _as_plan(cfg, plan, shards=shards)
@@ -227,7 +239,7 @@ def ff_blocks_sparse(params, cfg: ModelConfig, x_blocks, plan,
         ids = S.balanced_topk_tiles(scores, plan.k_max, ff.tile,
                                     sel_shards)
         y = S.ffn_sparse_batched(params, x, ids, ff.tile, cfg.act,
-                                 k_valid=k_valid)
+                                 k_valid=k_valid, layer=layer)
         return _compensate(params, cfg, x, y)
 
     if is_dense is None:
@@ -235,18 +247,19 @@ def ff_blocks_sparse(params, cfg: ModelConfig, x_blocks, plan,
     zeros = lambda x: jnp.zeros(x.shape, x.dtype)
     y_sp = jax.lax.cond(jnp.any(~is_dense), sparse, zeros, x_blocks)
     y_dn = jax.lax.cond(jnp.any(is_dense),
-                        lambda x: S.ffn_dense(params, x, cfg.act),
+                        lambda x: ff_dense(params, cfg, x, layer),
                         zeros, x_blocks)
     return jnp.where(is_dense[:, None, None], y_dn, y_sp)
 
 
 def ff_decode_sparse(params, cfg: ModelConfig, x_tok, plan,
-                     shards: int = 1, k_valid=None):
+                     shards: int = 1, k_valid=None, layer=None):
     """Decode-time sparsity (paper Table 3): block == current token.
     k_valid: traced scalar or [B] — per-row counts carry per-REQUEST
-    effort tiers through the one batched decode executable."""
+    effort tiers through the one batched decode executable. `layer`:
+    stacked FFN matrices, as in `ff_block_sparse`."""
     return ff_block_sparse(params, cfg, x_tok, plan, shards,
-                           k_valid=k_valid)
+                           k_valid=k_valid, layer=layer)
 
 
 # ----------------------------------------------------------- scheduling

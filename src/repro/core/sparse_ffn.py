@@ -34,6 +34,23 @@ def ffn_spec(d_model: int, d_ff: int, gated: bool = True, dtype=jnp.float32):
     return sp
 
 
+#: The FFN matrices a layer scan may hand over as whole [L, ...] stacks
+#: (with the layer index) instead of one layer's slices.
+FFN_MATRICES = ("wg", "wu", "wd")
+
+
+def ffn_at(params, layer):
+    """One layer's FFN params from params whose matrices are the stacked
+    [L, ...] leaves: each matrix indexed at the traced `layer` (XLA's
+    dots read such a slice in place). Other leaves (predictor,
+    compensator) are one layer's already. layer None: params are one
+    layer's throughout, returned as they are."""
+    if layer is None:
+        return params
+    return {k: (jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+                if k in FFN_MATRICES else v) for k, v in params.items()}
+
+
 def ffn_hidden(params, x, act: str = "silu"):
     """Post-activation hidden h: [..., F] (used for labels + mask path)."""
     up = jnp.einsum("...d,df->...f", x, params["wu"],
@@ -169,7 +186,7 @@ def ffn_sparse_gather(params, x_block, tile_ids, tile: int, act: str = "silu",
 
 
 def ffn_sparse_batched(params, x_blocks, tile_ids, tile: int,
-                       act: str = "silu", k_valid=None):
+                       act: str = "silu", k_valid=None, layer=None):
     """x_blocks [B, N, D], tile_ids [B, K] -> [B, N, D] — every row
     selects its own tiles (the multi-request prefill hot path).
 
@@ -184,7 +201,12 @@ def ffn_sparse_batched(params, x_blocks, tile_ids, tile: int,
     FLOP skip on TPU), the XLA paths mask the hidden tiles. This is
     the mechanism behind per-layer SparsityPlan counts (scalar, riding
     the layer scan) and per-request effort tiers at decode ([B], from
-    traced plan ids)."""
+    traced plan ids).
+
+    layer: optional traced int32 — params' FFN matrices are the stacked
+    [L, ...] leaves and this is the layer to compute. The kernel reads
+    its tiles out of the stacks in place (kernels/sparse_ffn/ops.py);
+    the fallback path takes the layer's slices (`ffn_at`)."""
     if k_valid is not None:
         k_valid = jnp.broadcast_to(jnp.asarray(k_valid, jnp.int32),
                                    x_blocks.shape[:1])
@@ -192,8 +214,9 @@ def ffn_sparse_batched(params, x_blocks, tile_ids, tile: int,
         from repro.kernels.sparse_ffn import ops
         y = ops.sparse_ffn_batched_op(x_blocks, params["wg"], params["wu"],
                                       params["wd"], tile_ids, tile=tile,
-                                      k_valid=k_valid)
+                                      k_valid=k_valid, layer=layer)
         return y.astype(x_blocks.dtype)
+    params = ffn_at(params, layer)
     if k_valid is None:
         return jax.vmap(
             lambda xb, ids: ffn_sparse_gather(params, xb, ids, tile, act)

@@ -63,28 +63,34 @@ def serving_kernel_cases(dense_cfg, moe_cfg, *, batch: int,
         return (jax.random.normal(key, shape, jnp.float32)
                 * scale).astype(dtype)
 
-    def ffn_weights(key):
+    def ffn_weights(key, lead=()):
         kg, ku, kd = jax.random.split(key, 3)
-        return (normal(kg, (D, F), D ** -0.5), normal(ku, (D, F), D ** -0.5),
-                normal(kd, (F, D), F ** -0.5))
+        return (normal(kg, lead + (D, F), D ** -0.5),
+                normal(ku, lead + (D, F), D ** -0.5),
+                normal(kd, lead + (F, D), F ** -0.5))
 
-    def sparse_ffn_args(n_tok):
+    def sparse_ffn_args(n_tok, n_layers=None):
+        """n_layers: the weights are [n_layers, ...] stacks, as the dense
+        model's layer scans pass them, read at their last layer."""
         def make(key):
             kx, kw, ki, kc = jax.random.split(key, 4)
             ids = jax.vmap(lambda k: jax.random.permutation(
                 k, n_tiles)[:k_ffn])(jax.random.split(ki, B))
             k_valid = jax.random.randint(kc, (B,), 1, k_ffn + 1)
-            return ((normal(kx, (B, n_tok, D)),) + ffn_weights(kw)
-                    + (ids.astype(jnp.int32), k_valid))
+            lead = () if n_layers is None else (n_layers,)
+            layer = () if n_layers is None else (jnp.int32(n_layers - 1),)
+            return ((normal(kx, (B, n_tok, D)),) + ffn_weights(kw, lead)
+                    + (ids.astype(jnp.int32), k_valid) + layer)
         return make
 
-    def sparse_ffn_case(n_tok):
+    def sparse_ffn_case(n_tok, n_layers=None):
         return KernelCase(
-            kernel=lambda x, wg, wu, wd, ids, kv: SFK.sparse_ffn_batched(
-                x, wg, wu, wd, ids, kv, tile=tile, block_n=min(n_tok, 128)),
-            oracle=lambda x, wg, wu, wd, ids, kv: SFR.sparse_ffn_batched_ref(
-                x, wg, wu, wd, ids, tile, k_valid=kv),
-            make_args=sparse_ffn_args(n_tok), tol=(bf16,))
+            kernel=lambda x, wg, wu, wd, ids, kv, *layer:
+            SFK.sparse_ffn_batched(x, wg, wu, wd, ids, kv, *layer, tile=tile,
+                                   block_n=min(n_tok, 128)),
+            oracle=lambda x, wg, wu, wd, ids, kv, *layer:
+            SFR.sparse_ffn_batched_ref(x, wg, wu, wd, ids, tile, kv, *layer),
+            make_args=sparse_ffn_args(n_tok, n_layers), tol=(bf16,))
 
     def dense_ffn_args(key):
         kx, kw = jax.random.split(key)
@@ -175,6 +181,8 @@ def serving_kernel_cases(dense_cfg, moe_cfg, *, batch: int,
     return {
         "sparse_ffn_batched": sparse_ffn_case(N),
         "sparse_ffn_batched_decode": sparse_ffn_case(1),
+        "sparse_ffn_batched_stacked": sparse_ffn_case(N, n_layers=2),
+        "sparse_ffn_batched_stacked_decode": sparse_ffn_case(1, n_layers=2),
         "dense_ffn": KernelCase(
             kernel=lambda *a: SFK.dense_ffn(*a, tile=tile, block_n=N),
             oracle=SFR.dense_ffn_ref, make_args=dense_ffn_args,

@@ -13,6 +13,18 @@ Grid: (num_token_blocks, K). VMEM working set per step:
   wd        [tile, D]
   out       [bn, D] (accumulator, revisited across the K axis)
 All MXU dims are multiples of 128 when D and tile are.
+
+Stacked operands (`sparse_ffn_batched` only): the weights may be the
+model's whole stacked leaves, W_gate/W_up [L, D, F] and W_down
+[L, F, D], with a traced `layer`. The leading block dimension is
+squeezed and every index map selects layer `layer`, so each grid step
+DMAs its [D, tile] / [tile, D] slab straight out of that layer's part
+of the stack. A layer scan then hands the kernel the stacks it closes
+over, not per-layer slices: a Pallas call cannot read a slice in place,
+so XLA would copy all three whole matrices every layer before the
+kernel reads the kept half of them. Same bytes moved, same arithmetic
+in the same order: the output is bit-identical to the 2-D call on the
+sliced weights.
 """
 from __future__ import annotations
 
@@ -134,7 +146,7 @@ def _sparse_ffn_batched_kernel(ids_ref, cnt_ref, x_ref, wg_ref, wu_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "block_n", "interpret"))
-def sparse_ffn_batched(x, wg, wu, wd, tile_ids, k_valid=None, *,
+def sparse_ffn_batched(x, wg, wu, wd, tile_ids, k_valid=None, layer=None, *,
                        tile: int = 128, block_n: int = 128,
                        interpret: bool = False):
     """Batched twin of `sparse_ffn` for multi-request prefill: every
@@ -154,14 +166,33 @@ def sparse_ffn_batched(x, wg, wu, wd, tile_ids, k_valid=None, *,
     so a layer-wise SparsityPlan's cheap layers and low-effort requests
     spend FLOPs proportional to their OWN counts while K stays static.
     None keeps all K tiles for every row (uniform plans).
+
+    Stacked operands: wg/wu [L, D, F] and wd [L, F, D] with `layer` a
+    traced int32 scalar read layer `layer` of the stacks in place (see
+    the module docstring); 2-D weights take no `layer`. The layer index
+    rides as one trailing element of the `cnt` scalar-prefetch array
+    ([B + 1]), not as an operand of its own: the custom call keeps its
+    six operands in the order (ids, cnt, x, wg, wu, wd), which the
+    benchmark's roofline reader names by position.
     """
     B, N, D = x.shape
-    F = wg.shape[1]
+    F = wg.shape[-1]
     K = tile_ids.shape[1]
+    stacked = wg.ndim == 3
+    if stacked != (layer is not None):
+        raise ValueError("sparse_ffn_batched: a layer index goes with "
+                         "stacked [L, ...] weights and only with them; "
+                         f"got weights of rank {wg.ndim}, layer {layer}")
     assert tile_ids.shape[0] == B
     assert N % block_n == 0 and F % tile == 0
     cnt = (jnp.full((B,), K, jnp.int32) if k_valid is None
            else jnp.broadcast_to(jnp.asarray(k_valid, jnp.int32), (B,)))
+    if stacked:
+        cnt = jnp.concatenate(
+            [cnt, jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))])
+    # stacked weights: squeezed layer dimension, indexed by cnt[B]
+    lead = (pl.squeezed,) if stacked else ()
+    at_layer = (lambda cnt: (cnt[B],)) if stacked else (lambda cnt: ())
 
     grid = (B, N // block_n, K)
 
@@ -175,15 +206,15 @@ def sparse_ffn_batched(x, wg, wu, wd, tile_ids, k_valid=None, *,
                              lambda b, n, k, ids, cnt: (b, n, 0)),
                 # dead steps clamp to row b's last live tile id — the
                 # revisited slab is already resident, no DMA issued
-                pl.BlockSpec((D, tile),
-                             lambda b, n, k, ids, cnt:
-                             (0, _live(ids, cnt[b], k, b))),
-                pl.BlockSpec((D, tile),
-                             lambda b, n, k, ids, cnt:
-                             (0, _live(ids, cnt[b], k, b))),
-                pl.BlockSpec((tile, D),
-                             lambda b, n, k, ids, cnt:
-                             (_live(ids, cnt[b], k, b), 0)),
+                pl.BlockSpec(lead + (D, tile),
+                             lambda b, n, k, ids, cnt: at_layer(cnt)
+                             + (0, _live(ids, cnt[b], k, b))),
+                pl.BlockSpec(lead + (D, tile),
+                             lambda b, n, k, ids, cnt: at_layer(cnt)
+                             + (0, _live(ids, cnt[b], k, b))),
+                pl.BlockSpec(lead + (tile, D),
+                             lambda b, n, k, ids, cnt: at_layer(cnt)
+                             + (_live(ids, cnt[b], k, b), 0)),
             ],
             out_specs=pl.BlockSpec((1, block_n, D),
                                    lambda b, n, k, ids, cnt: (b, n, 0)),

@@ -37,7 +37,7 @@ def sparse_ffn_ref(x, wg, wu, wd, tile_ids, tile: int, k_valid=None):
 
 
 def sparse_ffn_batched_ref(x, wg, wu, wd, tile_ids, tile: int,
-                           k_valid=None):
+                           k_valid=None, layer=None):
     """Batched oracle: x [B, N, D]; tile_ids [B, K] — each row selects
     its own tiles. Returns [B, N, D] float32.
 
@@ -49,7 +49,14 @@ def sparse_ffn_batched_ref(x, wg, wu, wd, tile_ids, tile: int,
 
     k_valid: optional traced [B] int32 — row b consumes only its first
     k_valid[b] selected tiles; the rest are masked out of the hidden
-    activations (the XLA twin of the kernel's pl.when skip)."""
+    activations (the XLA twin of the kernel's pl.when skip).
+
+    Stacked operands, as the kernel takes them: wg/wu [L, D, F] and wd
+    [L, F, D] with a traced int32 `layer` compute layer `layer`'s FFN,
+    the stacks indexed dynamically; 2-D weights take no `layer`."""
+    if layer is not None:
+        wg, wu, wd = (jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+                      for w in (wg, wu, wd))
     D, F = wg.shape
     n_tiles = F // tile
     g = jnp.take(wg.reshape(D, n_tiles, tile), tile_ids,

@@ -22,6 +22,7 @@ from repro.nn import param as PM
 from repro.nn import layers as L
 from repro.nn import attention as A
 from repro.core import fastforward as FF
+from repro.core import sparse_ffn as S
 from repro.models import chunked as CH
 from repro.distributed.sharding import constrain
 
@@ -139,6 +140,20 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=None):
 # ---------------------------------------------------------------- prefill
 
 
+def _scan_inputs(params):
+    """Split the stacked layer leaves for a serving layer scan:
+    (layers, layer ids, FFN stacks). The scan slices `layers`, which
+    lacks the FFN matrices, and carries the layer index `jnp.arange(L)`;
+    the body reads W_gate/W_up/W_down out of the whole [L, ...] `stacks`
+    at that index. The sparse FFN kernel DMAs its tiles straight out of
+    a stack; handed a per-layer slice, which a Pallas call cannot read
+    in place, XLA would copy all three whole matrices every layer."""
+    ffn = dict(params["layers"]["ffn"])
+    stacks = {k: ffn.pop(k) for k in S.FFN_MATRICES if k in ffn}
+    layers = dict(params["layers"], ffn=ffn)
+    return layers, jnp.arange(stacks["wu"].shape[0]), stacks
+
+
 def prefill_block(params, cfg: ModelConfig, tok_blk, cache, pos0, *,
                   is_dense=None, lengths=None, shards: int = 1,
                   plan=None, k_tiles=None, mesh=None):
@@ -171,9 +186,11 @@ def prefill_block(params, cfg: ModelConfig, tok_blk, cache, pos0, *,
     N = tok_blk.shape[1]
     x = L.embed(params["embed"], tok_blk).astype(cfg.dtype)
     positions = pos0 + jnp.arange(N)[None, :]
+    layers, layer_ids, stacks = _scan_inputs(params)
 
     def layer_body(x, layer_in):
-        lp, kc, vc, *rest = layer_in
+        lp, l, kc, vc, *rest = layer_in
+        fp = {**lp["ffn"], **stacks}
         rest = list(rest)
         k_l = rest.pop(0) if counts is not None else None
         a_l = rest.pop(0) if attn_counts is not None else None
@@ -191,21 +208,20 @@ def prefill_block(params, cfg: ModelConfig, tok_blk, cache, pos0, *,
         x = x + h
         xn2 = apply_norm(cfg, lp["ln2"], x)
         if plan is not None and cfg.shardmap_ffn and mesh is not None:
-            from repro.core.sparse_ffn import ffn_block_sparse_shardmap
             # the shardmap gather is shard-balanced -> uniform width
             y = jax.lax.cond(
                 is_dense,
-                lambda xx: FF.ff_dense(lp["ffn"], cfg, xx),
-                lambda xx: ffn_block_sparse_shardmap(
-                    lp["ffn"], cfg, xx, plan.k_max, mesh), xn2)
+                lambda xx: FF.ff_dense(fp, cfg, xx, l),
+                lambda xx: S.ffn_block_sparse_shardmap(
+                    S.ffn_at(fp, l), cfg, xx, plan.k_max, mesh), xn2)
         elif plan is not None:
-            y = FF.ff_block_sparse(lp["ffn"], cfg, xn2, plan,
-                                   shards, is_dense, k_valid=k_l)
+            y = FF.ff_block_sparse(fp, cfg, xn2, plan, shards, is_dense,
+                                   k_valid=k_l, layer=l)
         else:
-            y = FF.ff_dense(lp["ffn"], cfg, xn2)
+            y = FF.ff_dense(fp, cfg, xn2, l)
         return x + y, (kc, vc)
 
-    xs = (params["layers"], cache["k"], cache["v"])
+    xs = (layers, layer_ids, cache["k"], cache["v"])
     if counts is not None:
         xs = xs + (counts,)
     if attn_counts is not None:
@@ -257,9 +273,11 @@ def prefill_blocks(params, cfg: ModelConfig, tok_blks, cache, pos0s, *,
                    if plan is not None and plan.has_attn else None)
     N = tok_blks.shape[1]
     x = L.embed(params["embed"], tok_blks).astype(cfg.dtype)
+    layers, layer_ids, stacks = _scan_inputs(params)
 
     def layer_body(x, layer_in):
-        lp, kc, vc, *rest = layer_in
+        lp, l, kc, vc, *rest = layer_in
+        fp = {**lp["ffn"], **stacks}
         rest = list(rest)
         k_l = rest.pop(0) if counts is not None else None
         a_l = rest.pop(0) if attn_counts is not None else None
@@ -292,13 +310,13 @@ def prefill_blocks(params, cfg: ModelConfig, tok_blks, cache, pos0s, *,
         x = x + h
         xn2 = apply_norm(cfg, lp["ln2"], x)
         if plan is not None:
-            y = FF.ff_blocks_sparse(lp["ffn"], cfg, xn2, plan,
-                                    shards, is_dense, k_valid=k_l)
+            y = FF.ff_blocks_sparse(fp, cfg, xn2, plan, shards, is_dense,
+                                    k_valid=k_l, layer=l)
         else:
-            y = FF.ff_dense(lp["ffn"], cfg, xn2)
+            y = FF.ff_dense(fp, cfg, xn2, l)
         return x + y, (kc, vc)
 
-    xs = (params["layers"], cache["k"], cache["v"])
+    xs = (layers, layer_ids, cache["k"], cache["v"])
     if counts is not None:
         xs = xs + (counts,)
     if attn_counts is not None:
@@ -462,13 +480,15 @@ def decode_step(params, cfg: ModelConfig, token, cache, position,
     # single uniform plan -> counts_lp None: no counts ride, no masking
     # — the executable is the pre-plan decode step (bit-compat path)
     sel_plan, counts_lp = FF.decode_plan_setup(plans)
+    layers, layer_ids, stacks = _scan_inputs(params)
 
     def layer_body(x, layer_in):
         if counts_lp is None:
-            lp, kc, vc = layer_in
+            lp, l, kc, vc = layer_in
             k_row = None
         else:
-            lp, kc, vc, k_row = layer_in        # [n_plans] this layer
+            lp, l, kc, vc, k_row = layer_in     # [n_plans] this layer
+        fp = {**lp["ffn"], **stacks}
         xn = apply_norm(cfg, lp["ln1"], x)
         k_new, v_new = A.project_kv(lp["attn"], xn, positions,
                                     cfg.rope_theta)
@@ -499,13 +519,13 @@ def decode_step(params, cfg: ModelConfig, token, cache, position,
         xn2 = apply_norm(cfg, lp["ln2"], x)
         if sel_plan is not None:
             y = FF.ff_decode_sparse(
-                lp["ffn"], cfg, xn2, sel_plan, shards,
-                k_valid=FF.decode_k_valid(k_row, plan_ids))
+                fp, cfg, xn2, sel_plan, shards,
+                k_valid=FF.decode_k_valid(k_row, plan_ids), layer=l)
         else:
-            y = FF.ff_dense(lp["ffn"], cfg, xn2)
+            y = FF.ff_dense(fp, cfg, xn2, l)
         return x + y, (kc, vc)
 
-    xs = (params["layers"], cache["k"], cache["v"])
+    xs = (layers, layer_ids, cache["k"], cache["v"])
     if counts_lp is not None:
         xs = xs + (counts_lp,)
     x, (ks, vs) = jax.lax.scan(layer_body, x, xs)

@@ -1,5 +1,7 @@
 """Per-kernel validation: sparse/dense gated FFN Pallas kernels
 (interpret mode) vs pure-jnp oracles, swept over shapes and dtypes."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -169,6 +171,89 @@ def test_k_valid_dead_slot_ids_never_read():
     yb_b = sparse_ffn_batched(xb, wg, wu, wd, idsb_b, k_valid=counts,
                               tile=128, interpret=True)
     np.testing.assert_array_equal(np.asarray(yb_a), np.asarray(yb_b))
+
+
+@pytest.mark.parametrize("impl", ["kernel", "oracle"])
+@pytest.mark.parametrize("layer,n_tok,counts", [
+    (0, 128, None),          # first layer, every tile live
+    (2, 128, (1, 3)),        # last layer, per-row k_valid, dead steps
+    (1, 1, (3, 2)),          # decode-shaped: one token per row
+])
+def test_stacked_weights_equal_sliced_bitwise(impl, layer, n_tok, counts):
+    """Stacked operands: the batched kernel (interpret mode) and its
+    oracle on whole [L, D, F] / [L, F, D] stacks at traced `layer` give
+    bit for bit what they give on that layer's 2-D slices — the tiles
+    are read in place, same bytes, same arithmetic."""
+    L, B, D, F, tile, k = 3, 2, 128, 512, 128, 3
+    ks = jax.random.split(jax.random.key(7), 4)
+    wg = (jax.random.normal(ks[0], (L, D, F)) / np.sqrt(D)).astype(
+        jnp.bfloat16)
+    wu = (jax.random.normal(ks[1], (L, D, F)) / np.sqrt(D)).astype(
+        jnp.bfloat16)
+    wd = (jax.random.normal(ks[2], (L, F, D)) / np.sqrt(F)).astype(
+        jnp.bfloat16)
+    x = jax.random.normal(ks[3], (B, n_tok, D)).astype(jnp.bfloat16)
+    ids = make_batched_ids(B, F // tile, k, seed=layer)
+    k_valid = None if counts is None else jnp.asarray(counts, jnp.int32)
+    if impl == "kernel":
+        run = functools.partial(sparse_ffn_batched, tile=tile,
+                                block_n=min(n_tok, 128), interpret=True)
+        stacked = run(x, wg, wu, wd, ids, k_valid, jnp.int32(layer))
+        sliced = run(x, wg[layer], wu[layer], wd[layer], ids, k_valid)
+    else:
+        stacked = sparse_ffn_batched_ref(x, wg, wu, wd, ids, tile,
+                                         k_valid=k_valid,
+                                         layer=jnp.int32(layer))
+        sliced = sparse_ffn_batched_ref(x, wg[layer], wu[layer],
+                                        wd[layer], ids, tile,
+                                        k_valid=k_valid)
+    np.testing.assert_array_equal(np.asarray(stacked), np.asarray(sliced))
+    # another layer's weights give another answer: the index is honoured
+    other = (layer + 1) % L
+    assert not np.array_equal(
+        np.asarray(stacked),
+        np.asarray(sparse_ffn_batched_ref(x, wg[other], wu[other],
+                                          wd[other], ids, tile,
+                                          k_valid=k_valid)))
+
+
+@pytest.mark.parametrize("rows,in_place", [(2, True), (12, False)])
+def test_batched_op_reads_stacks_in_place_below_reread_cap(rows, in_place,
+                                                          monkeypatch):
+    """The kernel path hands the kernel the whole stacks while the call's
+    rows read at most STACKED_MAX_REREAD matrices' worth of tiles (here
+    rows * 2 tiles of 64 over d_ff 256: 1 and 6), the layer's 2-D slices
+    past it; the output is the same bits either way."""
+    from jax.extend import core as jcore
+    from repro.kernels.sparse_ffn import ops
+    L, D, F, tile, k = 2, 128, 256, 64, 2
+    ks = jax.random.split(jax.random.key(3), 4)
+    wg, wu = (jax.random.normal(kk, (L, D, F)) / np.sqrt(D)
+              for kk in ks[:2])
+    wd = jax.random.normal(ks[2], (L, F, D)) / np.sqrt(F)
+    x = jax.random.normal(ks[3], (rows, 1, D))
+    ids = make_batched_ids(rows, F // tile, k)
+    layer = jnp.int32(1)
+    op = functools.partial(ops.sparse_ffn_batched_op, tile=tile,
+                           use_kernel=True, layer=layer)
+    np.testing.assert_array_equal(
+        np.asarray(op(x, wg, wu, wd, ids)),
+        np.asarray(sparse_ffn_batched(x, wg[1], wu[1], wd[1], ids,
+                                      tile=tile, block_n=1,
+                                      interpret=True)))
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for v in eqn.params.values():
+                if isinstance(v, jcore.ClosedJaxpr):
+                    yield from pallas_calls(v.jaxpr)
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    (call,) = pallas_calls(jax.make_jaxpr(op)(x, wg, wu, wd, ids).jaxpr)
+    assert [v.aval.ndim for v in call.invars[3:]] == [3 if in_place
+                                                      else 2] * 3
 
 
 def test_kernel_flop_scaling():

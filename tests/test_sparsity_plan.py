@@ -8,6 +8,7 @@ bit-identical to an explicit uniform plan), and the serving contract:
 mixed-effort streams keep compile_counts flat and every request's
 greedy output depends only on its OWN plan.
 """
+import functools
 import json
 
 import numpy as np
@@ -435,6 +436,124 @@ def test_dual_budget_layerwise_importance():
     assert plan.has_attn
     assert plan.attn_counts[3] > plan.attn_counts[0]
     assert sum(plan.attn_counts) == round(0.5 * cfg.n_layers * 8)
+
+
+# ------------------------- model level: FFN weights read from the stacks
+
+
+SCAN_ENTRIES = ["prefill_blocks_slot", "prefill_blocks_paged", "decode_step"]
+
+
+def _scan_entry(cfg, entry):
+    """One of the dense model's serving layer scans as a function of the
+    params, on fixed random inputs: three rows, the first a dense
+    (boundary) block in prefill, non-zero caches, a layer-wise plan
+    whose counts leave dead tile slots."""
+    from repro.models import dense
+    N, L = cfg.ff.block_size, cfg.n_layers
+    Kv, dh = cfg.n_kv_heads, cfg.head_dim
+    plan = FF.resolve_plan(cfg, importance=np.linspace(0.2, 1.0, L))
+    assert not plan.is_uniform
+    rng = np.random.default_rng(5)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab, (3, N)), jnp.int32)
+    pos0s = jnp.asarray([0, N, 2 * N], jnp.int32)
+    is_dense = jnp.asarray([True, False, False])
+    lengths = jnp.asarray([N, 2 * N, 3 * N - 5], jnp.int32)
+    active = jnp.ones((3,), bool)
+
+    def kv(*shape):
+        return jnp.asarray(rng.normal(size=(L,) + shape), cfg.dtype)
+
+    if entry == "prefill_blocks_slot":
+        cache = {"k": kv(3, 4 * N, Kv, dh), "v": kv(3, 4 * N, Kv, dh)}
+        return lambda p: dense.prefill_blocks(
+            p, cfg, toks, cache, pos0s, is_dense=is_dense,
+            lengths=lengths, plan=plan)
+    if entry == "prefill_blocks_paged":
+        cache = {"k": kv(10, N, Kv, dh), "v": kv(10, N, Kv, dh)}
+        tables = jnp.asarray([[1, 0, 0], [2, 3, 0], [4, 5, 6]], jnp.int32)
+        return lambda p: dense.prefill_blocks(
+            p, cfg, toks, cache, pos0s, is_dense=is_dense,
+            lengths=lengths, active=active, page_tables=tables, plan=plan)
+    cache = {"k": kv(3, 4 * N, Kv, dh), "v": kv(3, 4 * N, Kv, dh)}
+    return lambda p: dense.decode_step(
+        p, cfg, toks[:, 0], cache, lengths, active=active, plan=plan)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("entry", SCAN_ENTRIES)
+def test_layer_scans_read_ffn_stacks_bit_identical(dense_setup, entry,
+                                                   use_kernel,
+                                                   monkeypatch):
+    """The slot and paged batched prefill and the decode step, whose
+    layer scans hand the FFN the whole W_gate/W_up/W_down stacks and
+    the layer index, give the same outputs and caches, bit for bit, as
+    when every FFN call gets that layer's 2-D weights (the per-layer
+    feed the scans used before) — dense rows mixed in, on the XLA
+    oracle and on the batched kernel in interpret mode."""
+    from repro.kernels.sparse_ffn import ops
+    cfg, params = dense_setup
+    monkeypatch.setattr(ops, "sparse_ffn_batched_op", functools.partial(
+        ops.sparse_ffn_batched_op, use_kernel=use_kernel))
+    run = _scan_entry(cfg, entry)
+    stacked = run(params)
+    pred_stack = jax.tree.leaves(params["layers"]["ffn"]["pred"])[0]
+
+    def scanned_layer(fp):
+        """The layer the scan is at, found from the predictor weights
+        it sliced, not from the index it passes."""
+        hit = pred_stack == jax.tree.leaves(fp["pred"])[0]
+        return jnp.argmax(jnp.all(hit, axis=tuple(range(1, hit.ndim))))
+
+    for name in ("ff_blocks_sparse", "ff_decode_sparse"):
+        def per_layer(fp, *a, layer=None, _fn=getattr(FF, name), **kw):
+            assert layer is not None and fp["wu"].ndim == 3
+            return _fn(S.ffn_at(fp, scanned_layer(fp)), *a, **kw)
+        monkeypatch.setattr(FF, name, per_layer)
+    sliced = run(params)
+    for got, want in zip(jax.tree.leaves(stacked), jax.tree.leaves(sliced),
+                         strict=True):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation of a jaxpr, inner jaxprs (scan and
+    cond bodies, nested jits) included."""
+    from jax.extend import core as jcore
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield from _pallas_calls(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("entry", SCAN_ENTRIES)
+def test_sparse_ffn_kernel_operands_are_the_stacks(dense_setup, entry,
+                                                   monkeypatch):
+    """Lowered with the kernel path on, every sparse FFN pallas_call of
+    the layer scans takes the whole stacked [L, ...] W_gate, W_up and
+    W_down as its weight operands (ids, cnt, x, wg, wu, wd): no
+    per-layer slice of them feeds the kernel, so XLA has no copy to
+    make."""
+    from repro.kernels.sparse_ffn import ops
+    cfg, params = dense_setup
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    jaxpr = jax.make_jaxpr(_scan_entry(cfg, entry))(params)
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert calls
+    ffn = params["layers"]["ffn"]
+    want = [ffn[k].shape for k in ("wg", "wu", "wd")]
+    assert want[0][0] == cfg.n_layers
+    for eqn in calls:
+        assert len(eqn.invars) == 6
+        assert [v.aval.shape for v in eqn.invars[3:]] == want
+        # the layer index rides in cnt, after the per-row counts
+        assert eqn.invars[1].aval.shape == (eqn.invars[2].aval.shape[0]
+                                            + 1,)
 
 
 def test_attn_budget_full_keep_bit_identical_prefill(dense_setup):
