@@ -1,114 +1,15 @@
 """Operations and bytes of the work a call must do, from the
-configuration's shapes and the cell's FastForward plan.
+configuration's shapes and the cell's FastForward plan; the counts of
+each architecture are in bench/archs/<arch>.py.
 
-Nothing here reads a counter of the program: a prefill row is
-(pos0, n_tokens, is_dense) as the scheduler hands it to the runtime, and
-the counts are those of the model's mathematics, whatever computes it.
-A multiply-add is 2 operations. Padding rows and tokens past a row's
-length are no work: what a kernel spends on them shows as lost share.
+Nothing there reads a counter of the program: a prefill row is
+(pos0, n_tokens, is_dense, final) as the scheduler hands it to the
+runtime, and the counts are those of the model's mathematics, whatever
+computes it. A multiply-add is 2 operations. Padding rows and tokens
+past a row's length are no work: what a kernel spends on them shows as
+lost share.
 """
 from __future__ import annotations
-
-import math
-
-
-class Shapes:
-    def __init__(self, config: dict):
-        ff = config["fastforward"]
-        self.L = config["num_hidden_layers"]
-        self.D = config["hidden_size"]
-        self.F = config["intermediate_size"]
-        self.H = config["num_attention_heads"]
-        self.Kv = config["num_key_value_heads"]
-        self.dh = config["head_dim"]
-        self.V = config["vocab_size"]
-        self.N = ff["block_size"]
-        self.tile = ff["tile"]
-        self.r = ff["predictor_dim"]
-        self.rc = ff["compensator_dim"]
-        n_tiles = self.F // self.tile
-        # tiles a sparse row keeps: ceil((1 - sparsity) * n_tiles), >= 1
-        self.k_tiles = min(max(math.ceil((1 - ff["sparsity"]) * n_tiles),
-                               1), n_tiles)
-        self.act_bytes = 2          # bf16 activations, weights and KV
-
-
-def attn_causal_flops(s: Shapes, pos0: int, n: int) -> float:
-    """QK^T and PV of n queries at positions pos0..pos0+n-1, each over
-    the keys 0..its own position (one layer)."""
-    keys = n * pos0 + n * (n + 1) // 2
-    return 4.0 * s.H * s.dh * keys
-
-
-def proj_flops(s: Shapes, n: int) -> float:
-    """q, k, v and output projections of n tokens (one layer)."""
-    return 2.0 * n * s.D * (2 * s.H * s.dh + 2 * s.Kv * s.dh)
-
-
-def sparse_ffn_flops(s: Shapes, n: int) -> float:
-    """Gate, up and down over the kept tiles only (one layer)."""
-    return 6.0 * n * s.D * s.k_tiles * s.tile
-
-
-def predictor_flops(s: Shapes, n: int) -> float:
-    """Attention pooling over n tokens, then D->r->F (one unit)."""
-    return 4.0 * n * s.D + 2.0 * s.D * s.r + 2.0 * s.r * s.F
-
-
-def compensator_flops(s: Shapes, n: int) -> float:
-    return 4.0 * n * s.D * s.rc
-
-
-def prefill_row_flops(s: Shapes, pos0: int, n: int, dense: bool,
-                      final: bool) -> float:
-    """Model operations of one prefill row: every layer, plus the
-    output head when this is the row's final block (one position)."""
-    if dense:
-        ffn = 6.0 * n * s.D * s.F
-    else:
-        ffn = (sparse_ffn_flops(s, n) + predictor_flops(s, n)
-               + compensator_flops(s, n))
-    per_layer = proj_flops(s, n) + attn_causal_flops(s, pos0, n) + ffn
-    return s.L * per_layer + (2.0 * s.D * s.V if final else 0.0)
-
-
-def sparse_ffn_kernel_work(s: Shapes, rows, on_chip=frozenset()) -> tuple:
-    """(operations, least bytes) of one `sparse_ffn_batched` prefill
-    call for one layer. rows: (pos0, n, is_dense, final) of the live
-    rows; the sparse ones are N tokens each (middle blocks are whole).
-    Least bytes: the kept tiles of W_gate, W_up and W_down read once
-    (rows that chose the same tiles share them), each row's input in
-    bf16 and its float32 output. `on_chip` names the operands ("x",
-    "wg", "wu", "wd", "out") that the compiled program placed in the
-    chip's on-chip memory before the call: the kernel moves none of
-    their bytes through HBM (the copy that placed them ran earlier, in
-    other device time)."""
-    sparse = [r for r in rows if not r[2]]
-    if not sparse:
-        return 0.0, 0.0
-    flops = sum(sparse_ffn_flops(s, s.N) for _ in sparse)
-    tiles = s.D * s.k_tiles * s.tile * s.act_bytes
-    part = {"x": len(sparse) * s.N * s.D * s.act_bytes,
-            "wg": tiles, "wu": tiles, "wd": tiles,
-            "out": len(sparse) * s.N * s.D * 4}
-    return flops, float(sum(v for k, v in part.items() if k not in on_chip))
-
-
-def attn_prefill_kernel_work(s: Shapes, rows, on_chip=frozenset()) -> tuple:
-    """(operations, least bytes) of one prefill attention call for one
-    layer, rows as above: causal attention of each live row's n queries
-    over its keys. Least bytes: q in bf16, the K and V the row's
-    queries reach, and the float32 output; none of an operand's ("q",
-    "k", "v", "out") that sits in on-chip memory, as above."""
-    flops = sum(attn_causal_flops(s, r[0], r[1]) for r in rows)
-    part = {"q": 0, "k": 0, "v": 0, "out": 0}
-    for pos0, n, _, _ in rows:
-        part["q"] += n * s.H * s.dh * s.act_bytes
-        part["out"] += n * s.H * s.dh * 4
-        part["k"] += (pos0 + n) * s.Kv * s.dh * s.act_bytes
-        part["v"] += (pos0 + n) * s.Kv * s.dh * s.act_bytes
-    return float(flops), float(sum(v for k, v in part.items()
-                                   if k not in on_chip))
 
 
 def least_time(flops: float, nbytes: float, peak: dict) -> float:

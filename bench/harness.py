@@ -13,7 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from bench import flops, loop, spec, weights
+from bench import archs, loop, spec, weights
 from bench import trace as TR
 from bench.peaks import peaks
 
@@ -86,11 +86,10 @@ def _new_scheduler(cfg, sz, runtime, seed):
 def setup(cell: spec.Cell, seed: int, check_kernels: bool = True) -> Server:
     """Weights from the seed, the runtime, its scheduler and pool, and
     warmup, which compiles (or loads) every program the window runs."""
-    from repro.models.registry import get_model
     from repro.serving.runtime import make_runtime
     cfg = spec.model_config(cell.config)
     sz = spec.sizes(cell.config, cell.traffic)
-    params = weights.make_params(get_model(cfg).specs(cfg), seed)
+    params = weights.make_params(cell.config, seed)
     runtime = make_runtime(cfg, params)
     rec = loop.Recorder(runtime, cfg.ff.block_size)
     sched = _new_scheduler(cfg, sz, runtime, seed)
@@ -103,12 +102,10 @@ def setup(cell: spec.Cell, seed: int, check_kernels: bool = True) -> Server:
 def reseed(server: Server, seed: int) -> None:
     """New weights and a fresh pool for another seed, on the programs
     already compiled."""
-    from repro.models.registry import get_model
     server.runtime.params = None
     server.sched = None
     gc.collect()
-    server.runtime.params = weights.make_params(
-        get_model(server.cfg).specs(server.cfg), seed)
+    server.runtime.params = weights.make_params(server.cell.config, seed)
     server.sched = _new_scheduler(server.cfg, server.sizes, server.runtime,
                                   seed)
     server.sched.warmup()
@@ -127,10 +124,12 @@ def run_window(server: Server, planned, seconds: float,
 
 @dataclasses.dataclass
 class Ctx:
-    """What a metric reader may read."""
+    """What a metric reader may read. `arch` is the configuration's
+    bench/archs module, `shapes` its Shapes."""
     config: dict
     traffic: dict
-    shapes: flops.Shapes
+    arch: object
+    shapes: object
     window: loop.Window
     peak: dict
     setup_s: float
@@ -141,9 +140,10 @@ class Ctx:
 
 def context(server: Server, window, setup_s, profiler=None, trace=None):
     kind = jax.devices()[0].device_kind
-    ctx = Ctx(server.cell.config, server.cell.traffic,
-              flops.Shapes(server.cell.config), window, peaks(kind),
-              setup_s)
+    config = server.cell.config
+    arch = archs.module(config)
+    ctx = Ctx(config, server.cell.traffic, arch, arch.Shapes(config),
+              window, peaks(kind), setup_s)
     if trace is not None:
         ctx.trace, ctx.trace_s = trace, profiler.seconds
         ctx.traced_calls = [c for c in window.calls

@@ -3,7 +3,8 @@ RMSNorm, grouped-query attention with rotate-half RoPE, a SwiGLU FFN and
 FastForward's sparse FFN (arXiv:2602.00397), in float32 jax.numpy at
 "highest" matmul precision, one sequence and one layer at a time. No
 kernel, no KV cache, no batching; it imports nothing of the program and
-draws its own weights from the seed (bench/weights.py).
+draws its own weights from the seed, by the rules the program's tree
+is drawn by (bench/archs/dense.py, bench/weights.py).
 
 FastForward, as the configuration states it:
   * the prompt is cut into blocks of `block_size` tokens; the first and
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
+from bench.archs import dense as A
 
 HI = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
@@ -83,14 +85,15 @@ class Dims:
 @functools.partial(jax.jit, static_argnames=("shapes",))
 def _draw_layer(key_data, i, shapes):
     root = jax.random.wrap_key_data(key_data)
-    return {p: W.draw(root, "layers/" + p, s, jnp.bfloat16, layer=i)
+    return {p: W.draw(A.RULES, root, "layers/" + p, s, jnp.bfloat16,
+                      layer=i)
             for p, s in shapes}
 
 
 @functools.partial(jax.jit, static_argnames=("path", "shape"))
 def _draw_top(key_data, path, shape):
-    return W.draw(jax.random.wrap_key_data(key_data), path, shape,
-                  jnp.bfloat16)
+    return W.draw(A.RULES, jax.random.wrap_key_data(key_data), path,
+                  shape, jnp.bfloat16)
 
 
 # ---------------------------------------------------------- arithmetic

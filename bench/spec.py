@@ -2,14 +2,19 @@
 its traffic file and its limits file, each found by name, and the
 deployment sizes derived from them.
 
-Nothing here is specific to one configuration or one traffic mix: a new
-cell is new files and a new entry in BENCHMARK.json.
+Nothing here is specific to one configuration, one architecture or one
+traffic mix: a new cell is new files and a new entry in BENCHMARK.json.
+What depends on the architecture is in bench/archs/<arch>.py, found by
+the configuration's `arch` key; a cell whose `arch` has no module there
+fails when it loads.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import pathlib
+
+from bench import archs
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -44,6 +49,7 @@ def load_cell(name: str, benchmark_path=None) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(ROOT / configs[w["config"]]["file"])
+    archs.module(config)      # an unknown arch fails here, naming its file
     traffic = load_json(BENCH_DIR / "traffic" / f"{w['traffic']}.json")
     limits = load_json(BENCH_DIR / "limits" / f"{name}.json")
     return Cell(
@@ -56,41 +62,9 @@ def load_cell(name: str, benchmark_path=None) -> Cell:
 
 
 def model_config(config: dict):
-    """The program's ModelConfig for a configuration file: every number
-    as the file states it."""
-    from repro.models.base import FastForwardConfig, ModelConfig
-    ff = config["fastforward"]
-    if config["hidden_act"] != "silu" or config["attention_bias"] \
-            or config["tie_word_embeddings"]:
-        raise ValueError(f"{config['name']}: the dense runtime serves "
-                         "untied silu SwiGLU without attention bias")
-    return ModelConfig(
-        name=config["name"], arch=config["arch"],
-        n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        d_head=config["head_dim"],
-        d_ff=config["intermediate_size"], vocab=config["vocab_size"],
-        rope_theta=config["rope_theta"], act="silu", gated=True,
-        qkv_bias=False, kv_layout=config["serving"]["kv_layout"],
-        param_dtype=config["torch_dtype"], remat=False,
-        source=config["source"],
-        ff=FastForwardConfig(
-            enabled=ff["enabled"], sparsity=ff["sparsity"],
-            block_size=ff["block_size"], tile=ff["tile"],
-            predictor_dim=ff["predictor_dim"],
-            compensator_dim=ff["compensator_dim"],
-            dense_first_block=ff["dense_first_block"],
-            dense_last_block=ff["dense_last_block"],
-            apply_to_decode=ff["apply_to_decode"],
-            use_compensator=ff["use_compensator"]))
-
-
-def kv_bytes_per_token(config: dict) -> int:
-    """K and V of every layer, in the served dtype (bf16: 2 bytes)."""
-    return (2 * config["num_hidden_layers"] * config["num_key_value_heads"]
-            * config["head_dim"] * 2)
+    """The program's ModelConfig for a configuration file, as its
+    architecture module (bench/archs) reads it."""
+    return archs.module(config).model_config(config)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,7 +87,7 @@ def sizes(config: dict, traffic: dict) -> Sizes:
     longest_prompt = traffic["prompt"]["max"]
     longest_out = traffic["output"]["max"]
     cache_len = -(-longest_prompt // N) * N + max(longest_out, 2)
-    per_tok = kv_bytes_per_token(config)
+    per_tok = archs.module(config).kv_bytes_per_token(config)
     budget = s["kv_budget_bytes"]
     P = s["max_prefill_batch"]
     if s["kv_layout"] == "slot":

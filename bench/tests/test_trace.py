@@ -117,8 +117,8 @@ def test_kernel_roofline_pairs_calls_with_program_executions():
     import types
     from bench import flops as FL
     shapes = types.SimpleNamespace(L=2, N=128)
-    work = lambda s, rows, on_chip: (0.0, 1e3 * len(rows)
-                                     * (0.5 if "wu" in on_chip else 1))
+    work = lambda s, rows, on_chip, layer: (
+        0.0, 1e3 * len(rows) * (0.5 if "wu" in on_chip else 1))
     call = lambda rows: types.SimpleNamespace(kind="prefill", rows=rows)
     sparse, dense = [(0, 128, False, False)], [(0, 128, True, False)]
     mods = [("jit__prefill_block_impl", 0, 100, ""),

@@ -6,17 +6,16 @@ import pathlib
 import jax
 import jax.numpy as jnp
 
-from bench import spec, weights
+from bench import weights
+from bench.archs import dense as A
 from bench.references import dense as RD
 
 DATA = pathlib.Path(__file__).parent / "data"
 
 
 def test_program_tree_equals_reference_draws():
-    from repro.models.registry import get_model
     config = json.loads((DATA / "tiny.json").read_text())
-    cfg = spec.model_config(config)
-    params = weights.make_params(get_model(cfg).specs(cfg), 2**33 + 1)
+    params = weights.make_params(config, 2**33 + 1)
     key = jax.random.key_data(weights.root_key(2**33 + 1))
     dims = RD.Dims(config)
     for i in range(dims.L):
@@ -32,18 +31,19 @@ def test_program_tree_equals_reference_draws():
 
 
 def test_high_seed_bits_change_the_weights():
-    a = weights.draw(weights.root_key(3), "layers/ffn/wg", (8, 8),
+    a = weights.draw(A.RULES, weights.root_key(3), "layers/ffn/wg", (8, 8),
                      jnp.float32, layer=0)
-    b = weights.draw(weights.root_key(3 + 2**32), "layers/ffn/wg", (8, 8),
-                     jnp.float32, layer=0)
+    b = weights.draw(A.RULES, weights.root_key(3 + 2**32),
+                     "layers/ffn/wg", (8, 8), jnp.float32, layer=0)
     assert not jnp.array_equal(a, b)
 
 
 def test_predictor_ranks_tiles_by_their_weight_in_w_down():
     root = weights.root_key(2**33 + 5)
-    wd = weights.draw(root, "layers/ffn/wd", (1024, 64), jnp.float32, 3)
-    w2 = weights.draw(root, "layers/ffn/pred/w2", (256, 1024),
+    wd = weights.draw(A.RULES, root, "layers/ffn/wd", (1024, 64),
                       jnp.float32, 3)
+    w2 = weights.draw(A.RULES, root, "layers/ffn/pred/w2",
+                      (256, 1024), jnp.float32, 3)
     n = 1024 // weights.TILE
     tile_norm = jnp.linalg.norm(wd.reshape(n, -1), axis=-1)
     tile_bias = w2.reshape(256, n, weights.TILE).mean(axis=(0, 2))

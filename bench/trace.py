@@ -200,7 +200,8 @@ def kernel_roofline(ctx, program: str, kernel: str, operands, work,
     order of dispatch) are paired with the executions of the programs named
     by `program`, in order of start; a call for which `runs(rows)` holds
     must hold one kernel execution per layer, any other none. Each
-    execution's work is `work(shapes, rows, on_chip)` (bench/flops.py).
+    execution's work is `work(shapes, rows, on_chip, layer)`, `layer`
+    its place among the call's executions (bench/archs).
     None where the counts do not pair up or nothing ran."""
     calls = [c for c in ctx.traced_calls if c.kind == "prefill"]
     mods = sorted(modules(ctx.trace, program), key=lambda e: e[1])
@@ -215,10 +216,10 @@ def kernel_roofline(ctx, program: str, kernel: str, operands, work,
                   if e[1] + e[2] <= m0 + md]
         if len(inside) != (ctx.shapes.L if runs(call.rows) else 0):
             return None
-        for e in inside:
+        for layer, e in enumerate(inside):
             on_chip = on_chip_operands(e[0], operands) or frozenset()
-            least += least_time(*work(ctx.shapes, call.rows, on_chip),
-                                ctx.peak)
+            least += least_time(*work(ctx.shapes, call.rows, on_chip,
+                                      layer), ctx.peak)
             spent += e[2] / 1e9
     return 100.0 * least / spent if spent else None
 
